@@ -137,11 +137,10 @@ BASELINES = {
 # ---------------------------------------------------------------------------
 
 def smoke_models_for(g: GraphSpec):
-    """Map every model the graph names onto a tiny smoke config so the
-    real continuous-batching engines can run it on CPU."""
-    from repro.configs import get_smoke
-    names = {g.nodes[n].model for n in g.llm_nodes()}
-    return {m: get_smoke("qwen3-1.7b").replace(name=m) for m in names}
+    """Every model the graph names on the served arch's smoke preset, so
+    the real continuous-batching engines can run it on CPU."""
+    from repro.configs import models_for
+    return models_for(g, smoke=True)
 
 
 def make_real_processor(workload="w+", n=6, workers=2, decode_cap=4,
@@ -244,14 +243,14 @@ def run_kernel_ab(workload="wt", n=4, workers=2, decode_cap=4):
     multi-page double-buffered KV blocks plus the fused append
     (eliminating the separate scatter dispatch per decode step).
     Temp-0 outputs are bitwise identical across arms — masked pages are
-    exact no-ops in the online-softmax recurrence.  On CPU hosts the
-    kernels run under the Pallas interpreter (``interpret=True``), where
-    timings are meaningless; callers gate throughput claims on the
-    returned flag."""
-    import jax
-    from repro.kernels import env_interpret
+    exact no-ops in the online-softmax recurrence.  The kernels run
+    under the Pallas interpreter only when asked
+    (REPRO_PALLAS_INTERPRET=1), where timings are meaningless; callers
+    gate throughput claims on the returned flag.  Without a TPU and
+    without that request it raises."""
+    from benchmarks.kernel_bench import interpret_mode
     from repro.runtime.executors import EngineHost
-    interp = env_interpret(False) or jax.default_backend() != "tpu"
+    interp = interpret_mode()
     impl = "pallas_interpret" if interp else "pallas"
     reps = {}
     for variant in ("fused", "single"):

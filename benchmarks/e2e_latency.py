@@ -176,9 +176,10 @@ def kernel_rows(n_queries: int = 4, workers: int = 2,
     baseline on warm WT hosts (both arms paged + Pallas).  Rows carry
     tokens/s-per-device — the quantity the nightly gate and the >=1.3x
     fused-vs-single check track — plus ``outputs_match`` pinning the
-    bitwise-identity contract.  On CPU hosts (``interpret: true``) the
-    throughput numbers measure the Pallas interpreter and every timing
-    gate skips them."""
+    bitwise-identity contract.  Under the Pallas interpreter
+    (``interpret: true``, asked for with REPRO_PALLAS_INTERPRET=1) the
+    throughput numbers measure the interpreter and every timing gate
+    skips them."""
     from benchmarks.common import run_kernel_ab
     rep_f, rep_s, interp = run_kernel_ab("wt", n_queries, workers,
                                          decode_cap)
@@ -197,5 +198,7 @@ def kernel_rows(n_queries: int = 4, workers: int = 2,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for r in run(256, include_real=True):
         print(r)

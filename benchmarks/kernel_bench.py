@@ -11,12 +11,13 @@ scores achieved HBM bandwidth against the ``launch/roofline.py`` peaks
 ``autotune.json`` table that ``kernels/paged_decode_attention/ops.py``
 consults at call time.
 
-Persisting REFUSES to run when the sweep was measured in Pallas
-interpret mode (``REPRO_PALLAS_INTERPRET=1``, or the automatic fallback
-on a CPU-only host): interpret timings measure the interpreter, not the
-TPU, and a table seeded from them would be meaningless.  Rows from an
-interpret sweep are still exported (marked ``interpret: true``) so the
-CI smoke exercises the full path; the nightly tokens/s gate in
+The sweep runs the kernels on a TPU.  Without one it raises, unless the
+Pallas interpreter is asked for (``REPRO_PALLAS_INTERPRET=1``).
+Persisting REFUSES to run when the sweep was measured in interpret mode:
+interpret timings measure the interpreter, not the TPU, and a table
+seeded from them would be meaningless.  Rows from an interpret sweep
+are still exported (marked ``interpret: true``) so the CI smoke
+exercises the full path; the nightly tokens/s gate in
 ``benchmarks/smoke.py`` likewise skips interpret rows.
 """
 from __future__ import annotations
@@ -32,6 +33,7 @@ import numpy as np
 
 from repro.kernels import env_interpret
 from repro.kernels.paged_decode_attention import ops as paged_ops
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.roofline import paged_decode_cost, pct_of_roofline
 
 # (name, B, H, Hkv, Dh, page_size, n_pages) — smoke first; the larger
@@ -53,9 +55,17 @@ CANDIDATES = [{"variant": "single", "pages_per_block": 1,
 
 
 def interpret_mode() -> bool:
-    """True when timings would measure the Pallas interpreter: the env
-    override is set, or there is no TPU to compile for."""
-    return env_interpret(False) or jax.default_backend() != "tpu"
+    """True when a measurement runs (and times) the Pallas interpreter,
+    which must be asked for with REPRO_PALLAS_INTERPRET=1; with neither
+    that request nor a TPU it raises."""
+    if env_interpret(False):
+        return True
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"no TPU backend ({jax.default_backend()}): ask for the Pallas "
+            f"interpreter (REPRO_PALLAS_INTERPRET=1) to run the kernels "
+            f"without one")
+    return False
 
 
 def _make_inputs(B, H, Hkv, Dh, page_size, n_pages, seed=0):
@@ -187,6 +197,7 @@ def main() -> int:
     ap.add_argument("--reps", type=int, default=3)
     args = ap.parse_args()
 
+    enable_compile_cache()
     rows = bench_rows(smoke=args.smoke, reps=args.reps)
     if args.json:
         print(json.dumps(rows, indent=1))

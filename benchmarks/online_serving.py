@@ -264,6 +264,8 @@ def session_stream_rows(n_queries: int = 12, workers: int = 2,
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for r in run(64):
         print(r)
     for r in real_stream_rows():

@@ -15,6 +15,7 @@ import time
 
 from benchmarks import (ablation, case_study, e2e_latency, online_serving,
                         optimality, scalability, sensitivity)
+from repro.launch.compile_cache import enable_compile_cache
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "experiments", "results")
 
@@ -25,6 +26,7 @@ def main() -> int:
                     help="paper-scale N=1024 (slower)")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     n = 1024 if args.full else 256
 
     sections = {

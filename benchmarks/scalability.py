@@ -90,5 +90,7 @@ def recovery_rows() -> List[Dict]:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     for r in run() + scale_rows() + recovery_rows():
         print(r)

@@ -40,6 +40,7 @@ import time
 from typing import Dict, List
 
 from benchmarks import e2e_latency, kernel_bench, online_serving, scalability
+from repro.launch.compile_cache import enable_compile_cache
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "experiments", "results")
 
@@ -161,6 +162,7 @@ def check_inversions(sections: Dict[str, List[Dict]]) -> List[str]:
 
 
 def main() -> int:
+    enable_compile_cache()
     sections = {
         "BENCH_e2e_latency": lambda: e2e_latency.run(
             64, include_real=True),

@@ -11,7 +11,7 @@ preserves outputs bit-for-bit.
 import argparse
 import time
 
-from repro.configs import get_smoke
+from repro.configs import models_for
 from repro.core import (CostModel, EpochDPSolver, HARDWARE, PAPER_MODELS,
                         SolverConfig, consolidate)
 from repro.runtime import ProcessorConfig, RealProcessor
@@ -31,9 +31,9 @@ def main():
     cons = consolidate(graph, bindings)
     db = build_database(dbname)
     tools = ToolRuntime(db, latency_scale=0.0)
-    # the three serving models are hosted as tiny same-family JAX models
-    models = {m: get_smoke("qwen3-1.7b").replace(name=m)
-              for m in ("qwen3-14b", "qwen3-32b", "gpt-oss-20b")}
+    # every serving model is hosted as the tiny smoke preset (CPU run;
+    # the plan below names its hardware profile explicitly)
+    models = models_for(graph, smoke=True)
 
     cm = CostModel(graph, HARDWARE["h200"], PAPER_MODELS,
                    batch_sizes={n: cons.macro(n).n_unique

@@ -1,7 +1,9 @@
 """Architecture registry: ``--arch <id>`` resolves here.
 
 ``get_config(arch)`` returns the FULL assigned config; ``get_smoke(arch)``
-returns the reduced same-family config used by CPU smoke tests.
+returns the reduced same-family config used by CPU smoke tests;
+``models_for(graph)`` maps the model ids a workload names onto the
+config that serves them.
 ``input_specs(cfg, shape)`` builds ShapeDtypeStruct stand-ins for the
 dry-run (no device allocation).
 """
@@ -45,6 +47,21 @@ def get_config(arch: str) -> ModelConfig:
 
 def get_smoke(arch: str) -> ModelConfig:
     return _module(arch).smoke_config()
+
+
+# the architecture behind every model id a workload names
+SERVED_ARCH = "qwen3-1.7b"
+
+
+def models_for(graph, arch: str = SERVED_ARCH,
+               smoke: bool = False) -> Dict[str, ModelConfig]:
+    """``{model id: ModelConfig}`` for every model the graph's LLM nodes
+    name: ``arch`` at its published widths (``smoke``: its reduced
+    preset, for CPU runs), renamed to the id so engines, switches and
+    reports keep the workload's names."""
+    base = get_smoke(arch) if smoke else get_config(arch)
+    names = {graph.nodes[n].model for n in graph.llm_nodes()}
+    return {m: base.replace(name=m) for m in sorted(names)}
 
 
 def all_configs() -> Dict[str, ModelConfig]:
@@ -92,5 +109,5 @@ def input_specs(cfg: ModelConfig, shape: ShapeSpec) -> Dict[str, jax.ShapeDtypeS
 __all__ = [
     "ModelConfig", "MoEConfig", "ShapeSpec", "SHAPES", "ARCH_IDS",
     "get_config", "get_smoke", "all_configs", "input_specs",
-    "shape_applicable",
+    "shape_applicable", "SERVED_ARCH", "models_for",
 ]

@@ -23,7 +23,7 @@ from repro.core.consolidate import (
 from repro.core.cost_model import (
     A100, H100, H200, HARDWARE, PAPER_MODELS, TPU_V5E, CostModel,
     EpochWeights, HardwareCalibration, HardwareProfile, LLMProfile,
-    OperatorProfiler, profile_from_config,
+    OperatorProfiler, hardware_for_device_kind, profile_from_config,
 )
 from repro.core.graphspec import GraphSpec, LLMDag, NodeSpec, NodeType
 from repro.core.optimality import optimality_score
@@ -42,6 +42,7 @@ __all__ = [
     "CostModel", "EpochWeights", "HardwareCalibration",
     "HardwareProfile",
     "LLMProfile", "OperatorProfiler", "profile_from_config", "HARDWARE",
+    "hardware_for_device_kind",
     "PAPER_MODELS", "H200", "H100", "A100", "TPU_V5E", "GraphSpec",
     "LLMDag", "NodeSpec", "NodeType", "optimality_score",
     "BranchAndBoundOracle", "parse_workflow", "render", "static_signature",

@@ -54,6 +54,21 @@ TPU_V5E = HardwareProfile("tpu_v5e", 197e12, 819e9, 16e9, 32e9,
 
 HARDWARE = {h.name: h for h in (H200, H100, A100, TPU_V5E)}
 
+# ``jax.devices()[0].device_kind`` -> the profile real mode plans with
+DEVICE_KINDS = {"TPU v5 lite": TPU_V5E}
+
+
+def hardware_for_device_kind(kind: str) -> HardwareProfile:
+    """The profile of the device the engines run on; a kind with no
+    profile is an error (a run that cannot name its hardware would be
+    planned against some other chip's roofline)."""
+    if kind not in DEVICE_KINDS:
+        raise ValueError(
+            f"no hardware profile for device kind {kind!r} (known: "
+            f"{sorted(DEVICE_KINDS)}); name one of {sorted(HARDWARE)} "
+            f"explicitly")
+    return DEVICE_KINDS[kind]
+
 
 # ---------------------------------------------------------------------------
 # model profiles (the LLMs *served inside workflows*; paper: Qwen3-14B/32B,

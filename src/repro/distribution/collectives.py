@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.training.grad_compress import dequantize, quantize_error_feedback
@@ -46,13 +45,13 @@ def sharded_decode_attention(q, k, v, q_positions, kv_positions, *,
         den = jnp.where(den == 0.0, 1.0, den)
         return (num / den[..., None]).astype(qb.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(None, None, None), P(None, kv_axis, None, None),
                   P(None, kv_axis, None, None), P(None),
                   P(None, kv_axis)),
         out_specs=P(None, None, None),
-        check_rep=False,
+        check_vma=False,
     )(q, k, v, q_positions, kv_positions)
 
 
@@ -80,8 +79,8 @@ def compressed_psum_grads(grads: Any, err_state: Any, *, mesh: Mesh,
                 jax.tree.unflatten(treedef, [o[1] for o in outs]))
 
     spec = jax.tree.map(lambda _: P(), grads)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec, spec), out_specs=(spec, spec),
-        check_rep=False,
+        check_vma=False,
     )(grads, err_state)

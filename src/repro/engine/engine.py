@@ -87,6 +87,14 @@ def _batched_sample(logits, keys, temps, *, vocab_size: int):
     return tokens, jnp.where(stoch[:, None], new_keys, keys)
 
 
+@functools.partial(jax.jit, static_argnums=0)
+def _init_params(cfg: ModelConfig, key):
+    """Seeded random weights in one compiled program (cached per config):
+    eagerly, every initializer op is its own dispatch and each weight
+    passes through a float32 copy before its cast."""
+    return build_model(cfg).init(key)
+
+
 @dataclass
 class EngineStats:
     prefill_tokens: int = 0
@@ -300,7 +308,11 @@ class InferenceEngine:
         if self.params is not None:
             return 0.0
         t0 = time.perf_counter()
-        self.params = self.model.init(jax.random.PRNGKey(self.seed))
+        # params depend on neither the served name nor the attention
+        # path: engines differing only there share one compiled init
+        self.params = _init_params(
+            self.cfg.replace(name="", attention_impl="xla"),
+            jax.random.PRNGKey(self.seed))
         jax.block_until_ready(jax.tree.leaves(self.params)[0])
         dt = time.perf_counter() - t0
         self.stats.model_loads += 1
@@ -324,6 +336,17 @@ class InferenceEngine:
 
     def param_bytes(self) -> int:
         return self.cfg.param_count() * 2          # bf16
+
+    def hbm_bytes(self) -> int:
+        """Device bytes this engine holds at its decode peak once loaded:
+        the params, the KV pool, and the pool-sized copy the paged decode
+        step's layer scan writes (its compiled temporaries)."""
+        if not self._paged_layout:
+            return self.param_bytes()
+        layers, kv_heads, head_dim = self._paged_layout
+        pool = PagedKVCache.pool_bytes(layers, self.num_pages,
+                                       self.page_size, kv_heads, head_dim)
+        return self.param_bytes() + 2 * pool
 
     # ----------------------------------------------------------- submission
     def submit(self, prompt: Sequence[int], *, max_new_tokens: int = 16,

@@ -326,11 +326,18 @@ class PagedKVCache:  # requires: InferenceEngine._cv | engine-loop
             self._unref_page(p)
 
     # --------------------------------------------------------------- sizing
+    @staticmethod
+    def pool_bytes(num_layers: int, num_pages: int, page_size: int,
+                   kv_heads: int, head_dim: int, dtype_bytes: int = 4) -> int:
+        """Device bytes of a K+V pool of this layout (float32, the pool's
+        default, unless told otherwise), before allocating one."""
+        return 2 * num_layers * num_pages * page_size * kv_heads \
+            * head_dim * dtype_bytes
+
     def hbm_bytes(self, dtype_bytes: Optional[int] = None) -> int:
         """Pool footprint in bytes.  Defaults to the POOL's element
         width — the old ``=2`` default silently assumed bf16 while the
         pool allocates f32, undercounting by 2x."""
-        if dtype_bytes is None:
-            dtype_bytes = self.dtype.itemsize
-        return 2 * self.num_layers * self.num_pages * self.page_size \
-            * self.kv_heads * self.head_dim * dtype_bytes
+        return self.pool_bytes(self.num_layers, self.num_pages,
+                               self.page_size, self.kv_heads, self.head_dim,
+                               dtype_bytes or self.dtype.itemsize)

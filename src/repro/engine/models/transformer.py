@@ -552,9 +552,10 @@ class TransformerLM:
             vp_l = vp_l.at[write_page, write_off].set(
                 v[:, 0].astype(vp_l.dtype), mode="drop")
             if use_pallas:
+                # the kernels read the pool as stored and upcast per page;
+                # casting it here would copy the whole layer pool each step
                 o = paged_decode_attention(
-                    q, kp_l.astype(self.dtype), vp_l.astype(self.dtype),
-                    page_table, pos,
+                    q, kp_l, vp_l, page_table, pos,
                     interpret=impl == "pallas_interpret",
                     variant=eff_variant)
             else:

@@ -4,9 +4,11 @@ Layout per kernel: ``kernel.py`` (pl.pallas_call + BlockSpec VMEM tiling),
 ``ops.py`` (jit'd public wrapper), ``ref.py`` (pure-jnp oracle used by the
 allclose sweeps in tests/).
 
-Kernels are TPU-TARGETED and validated with ``interpret=True`` on CPU
-(this container has no TPU).  The XLA reference path (same math) is what
-the dry-run compiles; the kernel/XLA switch is ``cfg.attention_impl``.
+Kernels are TPU-TARGETED.  On the CPU (the test suite) they run under the
+Pallas interpreter, asked for explicitly with ``interpret=True`` or
+``REPRO_PALLAS_INTERPRET=1``; ``tests/test_tpu_compile.py`` compiles them
+for a described TPU v5e, and ``chip_smoke.py`` runs them on the chip.
+The kernel/XLA switch is ``cfg.attention_impl``.
 
 * flash_attention      — causal/SWA prefill attention, online softmax
 * decode_attention     — GQA flash-decode over a (ring-buffer) KV cache,
@@ -25,8 +27,17 @@ the dry-run compiles; the kernel/XLA switch is ``cfg.attention_impl``.
 
 import os
 
+import jax
+
 
 def env_interpret(interpret: bool) -> bool:
-    """Force Pallas interpret mode via REPRO_PALLAS_INTERPRET=1 (CI runs
-    the kernel suite this way on CPU runners)."""
-    return interpret or os.environ.get("REPRO_PALLAS_INTERPRET", "") == "1"
+    """Pallas interpret mode: the caller's flag, or
+    REPRO_PALLAS_INTERPRET=1 (how CPU runs of the kernel suite ask for
+    it).  The override on a TPU backend is an error — it would swap the
+    device kernels for the interpreter without a trace in the results."""
+    forced = os.environ.get("REPRO_PALLAS_INTERPRET", "") == "1"
+    if forced and jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "REPRO_PALLAS_INTERPRET=1 on a TPU backend: the kernels would "
+            "run under the Pallas interpreter; unset it")
+    return interpret or forced

@@ -10,16 +10,6 @@ from repro.kernels import env_interpret
 from repro.kernels.decode_attention.kernel import decode_attention_kernel
 
 
-
-def _pick_block(s: int, target: int) -> int:
-    if s % target == 0:
-        return target
-    b = min(s, target)
-    while s % b:
-        b -= 1
-    return b
-
-
 @functools.partial(jax.jit, static_argnames=(
     "window", "block_t", "return_lse", "interpret"))
 def _decode_attention_jit(q, k, v, *, q_positions, kv_positions, window=0,
@@ -28,9 +18,8 @@ def _decode_attention_jit(q, k, v, *, q_positions, kv_positions, window=0,
     if squeeze:
         assert q.shape[1] == 1
         q = q[:, 0]
-    bt = _pick_block(k.shape[1], block_t)
     out, m, l = decode_attention_kernel(
-        q, k, v, q_positions, kv_positions, window=window, block_t=bt,
+        q, k, v, q_positions, kv_positions, window=window, block_t=block_t,
         interpret=interpret)
     if squeeze:
         out = out[:, None]

@@ -38,21 +38,21 @@ def _flash_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)            # (bq, Dh)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # (bk, Dh)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    qp = qp_ref[0, :]                                    # (bq,) int32
-    kp = kp_ref[0, :]                                    # (bk,)
+    q = q_ref[0, 0].astype(jnp.float32)                  # (bq, Dh)
+    k = k_ref[0, 0].astype(jnp.float32)                  # (bk, Dh)
+    v = v_ref[0, 0].astype(jnp.float32)
+    qp = qp_ref[0]                                       # (bq, 1) int32
+    kp = kp_ref[0]                                       # (1, bk)
 
     logits = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale      # (bq, bk)
 
-    mask = (kp >= 0)[None, :]
+    mask = kp >= 0
     if causal:
-        mask = mask & (kp[None, :] <= qp[:, None])
+        mask = mask & (kp <= qp)
     if window > 0:
-        mask = mask & (kp[None, :] > qp[:, None] - window)
+        mask = mask & (kp > qp - window)
     logits = jnp.where(mask, logits, NEG_INF)
 
     m_prev = m_ref[:, 0]                                 # (bq,)
@@ -69,7 +69,16 @@ def _flash_kernel(qp_ref, kp_ref, q_ref, k_ref, v_ref, o_ref,
     def _done():
         l = l_ref[:, 0]
         denom = jnp.where(l == 0.0, 1.0, l)              # fully-masked rows
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+
+
+def _pad_axis(x, axis: int, size: int, value=0):
+    pad = size - x.shape[axis]
+    if not pad:
+        return x
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(x, widths, constant_values=value)
 
 
 # vmem-budget: 2.0 MiB @ block_q=512 block_kv=512 Sq=4096 Skv=4096 Dh=128
@@ -77,35 +86,53 @@ def flash_attention_kernel(q, k, v, q_positions, kv_positions, *,
                            causal: bool, window: int,
                            block_q: int, block_kv: int,
                            interpret: bool = False):
-    """q: (B,Sq,H,Dh); k,v: (B,Skv,Hkv,Dh). Requires Sq%bq==0, Skv%bk==0."""
+    """q: (B,Sq,H,Dh); k,v: (B,Skv,Hkv,Dh); positions (B,S) int32, -1 =
+    invalid slot.
+
+    The kernel runs head-major — (B, H, S, Dh) — so each block's last two
+    dims are (rows, Dh), the layout the TPU tiles.  A sequence longer
+    than its block is padded to a whole number of blocks: padded KV slots
+    carry position -1 (masked), padded query rows are sliced off.
+    """
     B, Sq, H, Dh = q.shape
     Skv, Hkv = k.shape[1], k.shape[2]
     G = H // Hkv
     bq, bk = min(block_q, Sq), min(block_kv, Skv)
-    assert Sq % bq == 0 and Skv % bk == 0
-    n_q, n_kv = Sq // bq, Skv // bk
+    n_q, n_kv = -(-Sq // bq), -(-Skv // bk)
+    sq_pad, skv_pad = n_q * bq, n_kv * bk
+    assert sq_pad % bq == 0 and skv_pad % bk == 0
     grid = (B, H, n_q, n_kv)
+
+    qh = _pad_axis(q.swapaxes(1, 2), 2, sq_pad)           # (B,H,Sq',Dh)
+    kh = _pad_axis(k.swapaxes(1, 2), 2, skv_pad)          # (B,Hkv,Skv',Dh)
+    vh = _pad_axis(v.swapaxes(1, 2), 2, skv_pad)
+    qp = _pad_axis(q_positions.astype(jnp.int32), 1, sq_pad, -1)[:, :, None]
+    kp = _pad_axis(kv_positions.astype(jnp.int32), 1, skv_pad, -1)[:, None, :]
 
     kernel = functools.partial(
         _flash_kernel, scale=1.0 / math.sqrt(Dh), causal=causal,
         window=window, n_kv=n_kv)
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq), lambda b, h, iq, ik: (b, iq)),
-            pl.BlockSpec((1, bk), lambda b, h, iq, ik: (b, ik)),
-            pl.BlockSpec((1, bq, 1, Dh), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, bk, 1, Dh), lambda b, h, iq, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, Dh), lambda b, h, iq, ik: (b, ik, h // G, 0)),
+            pl.BlockSpec((1, bq, 1), lambda b, h, iq, ik: (b, iq, 0)),
+            pl.BlockSpec((1, 1, bk), lambda b, h, iq, ik: (b, 0, ik)),
+            pl.BlockSpec((1, 1, bq, Dh), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, bk, Dh),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec((1, 1, bk, Dh),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, Dh), lambda b, h, iq, ik: (b, iq, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Sq, H, Dh), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, bq, Dh),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, H, sq_pad, Dh), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, Dh), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(q_positions, kv_positions, q, k, v)
+    )(qp, kp, qh, kh, vh)
+    return out[:, :, :Sq].swapaxes(1, 2)

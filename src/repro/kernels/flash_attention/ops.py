@@ -10,27 +10,14 @@ from repro.kernels import env_interpret
 from repro.kernels.flash_attention.kernel import flash_attention_kernel
 
 
-
-def _pick_block(s: int, target: int) -> int:
-    """Largest divisor of s that is <= target (prefers target itself)."""
-    if s % target == 0:
-        return target
-    b = min(s, target)
-    while s % b:
-        b -= 1
-    return b
-
-
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "block_q", "block_kv", "interpret"))
 def _flash_attention_jit(q, k, v, *, q_positions, kv_positions, causal=True,
                          window=0, block_q=512, block_kv=512,
                          interpret=False):
-    bq = _pick_block(q.shape[1], block_q)
-    bk = _pick_block(k.shape[1], block_kv)
     return flash_attention_kernel(
         q, k, v, q_positions, kv_positions, causal=causal, window=window,
-        block_q=bq, block_kv=bk, interpret=interpret)
+        block_q=block_q, block_kv=block_kv, interpret=interpret)
 
 
 def flash_attention(q, k, v, *, q_positions, kv_positions, causal=True,

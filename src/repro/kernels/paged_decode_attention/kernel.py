@@ -6,10 +6,9 @@ pool, so the dense gather that used to materialize each sequence (the
 host ``_rebuild_view`` round-trip) never happens.  Three variants share
 the flash-decode recurrence from ``kernels/common``:
 
-* ``single``  — one physical page per grid step, fetched by BlockSpec
-  indexing through the scalar-prefetched page table
-  (``pltpu.PrefetchScalarGridSpec``).  DMA and compute serialize: the
-  pipeline stalls on every page fetch.  Kept as the A/B baseline.
+* ``single``  — one physical page (every KV head of it) per grid step,
+  fetched by BlockSpec indexing through the scalar-prefetched page
+  table (``pltpu.PrefetchScalarGridSpec``).  Kept as the A/B baseline.
 * ``blocked`` — the innermost grid dim covers ``pages_per_block >= 2``
   physical pages per step.  The pool stays in ANY/HBM and each block is
   hand-DMA'd into a 2-slot VMEM scratch ring, double-buffered: block
@@ -66,9 +65,10 @@ GRID_LAYOUTS = ("bh", "hb")
 def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
                          o_ref, m_out_ref, l_out_ref,
                          acc_ref, m_ref, l_ref, *,
-                         scale: float, page_size: int, n_pages: int):
+                         scale: float, page_size: int, n_pages: int,
+                         n_kv_heads: int):
     b = pl.program_id(0)
-    it = pl.program_id(2)
+    it = pl.program_id(1)
 
     @pl.when(it == 0)
     def _init():
@@ -76,37 +76,41 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0, :, :].astype(jnp.float32)            # (G, Dh)
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # (page, Dh)
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
     length = len_ref[b]                                  # query position
-
-    logits = qk_logits(q, k, scale)                      # (G, page)
-
     # token t of page slot j is position it*page_size + j in the
     # sequence; stale / unwritten slots sit past `length` and padding
     # rows carry length < 0 (everything masked)
     kv_pos = it * page_size + jax.lax.broadcasted_iota(
-        jnp.int32, (1, page_size), 1)[0]
-    mask = kv_pos <= length
+        jnp.int32, (1, page_size), 1)
+    mask = kv_pos <= length                              # (1, page)
 
-    acc_ref[...], m_ref[:, 0], l_ref[:, 0] = online_softmax_update(
-        logits, mask[None, :], v, acc_ref[...], m_ref[:, 0], l_ref[:, 0])
+    for h in range(n_kv_heads):          # the page block holds every head
+        q = q_ref[0, h].astype(jnp.float32)              # (G, Dh)
+        k = k_ref[0, :, h, :].astype(jnp.float32)        # (page, Dh)
+        v = v_ref[0, :, h, :].astype(jnp.float32)
+        logits = qk_logits(q, k, scale)                  # (G, page)
+        acc_ref[h], m_ref[h, :, 0], l_ref[h, :, 0] = online_softmax_update(
+            logits, mask, v, acc_ref[h], m_ref[h, :, 0], l_ref[h, :, 0])
 
     @pl.when(it == n_pages - 1)
     def _done():
-        out, m, l = finalize_online_softmax(
-            acc_ref[...], m_ref[:, 0], l_ref[:, 0])
-        o_ref[0, 0, :, :] = out.astype(o_ref.dtype)
-        m_out_ref[0, 0, :, 0] = m
-        l_out_ref[0, 0, :, 0] = l
+        for h in range(n_kv_heads):
+            out, m, l = finalize_online_softmax(
+                acc_ref[h], m_ref[h, :, 0], l_ref[h, :, 0])
+            o_ref[0, h] = out.astype(o_ref.dtype)
+            m_out_ref[0, h, :, 0] = m
+            l_out_ref[0, h, :, 0] = l
 
 
-# vmem-budget: 0.25 MiB @ page_size=64 Dh=128 H=32 Hkv=8
+# vmem-budget: 0.6 MiB @ page_size=64 Dh=128 H=32 Hkv=8
 def paged_decode_attention_kernel(q, k_pages, v_pages, page_table, lengths,
                                   *, interpret: bool = False):
     """q: (B,H,Dh); k_pages/v_pages: (P, page, Hkv, Dh) — the pool;
     page_table: (B, n_pages) int32; lengths: (B,) int32 (-1 = padding).
+
+    One grid step per (row, page): the BlockSpec fetches the whole
+    physical page — every KV head, so the block's last two dims are the
+    pool's own (Hkv, Dh) — and the kernel attends each head in turn.
 
     Returns (out (B,H,Dh), m (B,H), l (B,H)).
     """
@@ -114,32 +118,32 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, page_table, lengths,
     P, page_size, Hkv, _ = k_pages.shape
     n_pages = page_table.shape[1]
     G = H // Hkv
-    grid = (B, Hkv, n_pages)
+    grid = (B, n_pages)
     qg = q.reshape(B, Hkv, G, Dh)
 
     kernel = functools.partial(
         _paged_decode_kernel, scale=1.0 / math.sqrt(Dh),
-        page_size=page_size, n_pages=n_pages)
+        page_size=page_size, n_pages=n_pages, n_kv_heads=Hkv)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,               # page_table, lengths
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, G, Dh), lambda b, h, i, pt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, Dh),
-                         lambda b, h, i, pt, ln: (pt[b, i], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, Dh),
-                         lambda b, h, i, pt, ln: (pt[b, i], 0, h, 0)),
+            pl.BlockSpec((1, Hkv, G, Dh), lambda b, i, pt, ln: (b, 0, 0, 0)),
+            pl.BlockSpec((1, page_size, Hkv, Dh),
+                         lambda b, i, pt, ln: (pt[b, i], 0, 0, 0)),
+            pl.BlockSpec((1, page_size, Hkv, Dh),
+                         lambda b, i, pt, ln: (pt[b, i], 0, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, G, Dh), lambda b, h, i, pt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, G, 1), lambda b, h, i, pt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, G, 1), lambda b, h, i, pt, ln: (b, h, 0, 0)),
+            pl.BlockSpec((1, Hkv, G, Dh), lambda b, i, pt, ln: (b, 0, 0, 0)),
+            pl.BlockSpec((1, Hkv, G, 1), lambda b, i, pt, ln: (b, 0, 0, 0)),
+            pl.BlockSpec((1, Hkv, G, 1), lambda b, i, pt, ln: (b, 0, 0, 0)),
         ],
         scratch_shapes=[
-            pltpu.VMEM((G, Dh), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
-            pltpu.VMEM((G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, Dh), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
+            pltpu.VMEM((Hkv, G, 1), jnp.float32),
         ],
     )
     out, m, l = pl.pallas_call(
@@ -345,8 +349,8 @@ def paged_decode_attention_blocked_kernel(q, k_pages, v_pages, page_table,
         grid=grid,
         in_specs=[
             q_spec,
-            pl.BlockSpec(memory_space=pltpu.ANY),    # k pool stays in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),    # v pool stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),    # k pool stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),    # v pool stays in HBM
         ],
         out_specs=[o_spec, ml_spec, ml_spec],
         scratch_shapes=[
@@ -406,7 +410,7 @@ def fused_paged_decode_attention_kernel(q, k_pages, v_pages, page_table,
 
     grid, q_spec, o_spec, ml_spec = _blocked_specs(B, Hkv, G, Dh, nb,
                                                    grid_layout)
-    any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,               # page_table, lengths
         grid=grid,

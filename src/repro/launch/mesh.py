@@ -13,29 +13,13 @@ state (the dry-run sets XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
-
-
-def compat_make_mesh(shape, axes):
-    """``jax.make_mesh`` across jax versions.
-
-    ``axis_types`` (and ``jax.sharding.AxisType``) only exist on newer
-    jax; older versions create Auto-typed meshes by default, so omitting
-    the argument is behaviour-preserving.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
-
-
-_make = compat_make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _make(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_debug_mesh(num_devices: int = 8):
@@ -46,4 +30,5 @@ def make_debug_mesh(num_devices: int = 8):
         if n % m == 0:
             model = m
             break
-    return _make((n // model, model), ("data", "model"))
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -74,8 +74,8 @@ from repro.distribution.collectives import (sharded_decode_attention,
 from repro.kernels.decode_attention.ref import decode_attention_ref
 from repro.training.grad_compress import init_error_state
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+auto = jax.sharding.AxisType.Auto
+mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(auto,) * 2)
 rng = np.random.default_rng(0)
 B, H, Hkv, Dh, T = 2, 4, 2, 16, 32
 q = jnp.asarray(rng.normal(size=(B, H, Dh)), jnp.float32)
@@ -89,7 +89,8 @@ ref = decode_attention_ref(q, k, v, q_positions=qp, kv_positions=kp)
 np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                            atol=2e-5, rtol=2e-5)
 
-mesh2 = compat_make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh2 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                      axis_types=(auto,) * 3)
 g = {"w": jnp.asarray(rng.normal(size=(16, 8)), jnp.float32)}
 mean_g, _ = compressed_psum_grads(g, init_error_state(g), mesh=mesh2)
 rel = float(jnp.abs(mean_g["w"] - g["w"]).max() / jnp.abs(g["w"]).max())

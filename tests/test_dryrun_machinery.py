@@ -19,8 +19,8 @@ from repro.launch.hlo_cost import analyze_hlo
 from repro.training.optimizer import AdamWConfig, adamw_init
 from repro.training.trainer import TrainerConfig, make_train_step
 
-from repro.launch.mesh import compat_make_mesh
-mesh = compat_make_mesh((2, 4), ("data", "model"))
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = get_smoke("qwen3-8b").replace(d_model=64, d_ff=256, vocab_size=512)
 model = build_model(cfg)
 pol = ShardingPolicy.for_mesh(mesh)

@@ -142,14 +142,45 @@ def test_paged_decode_cost_scales_with_live_pages():
 # sweep rows (interpret mode, tiny shape — structure only, no timing claims)
 # ---------------------------------------------------------------------------
 
-def test_bench_rows_smoke_structure():
+def test_bench_rows_smoke_structure(monkeypatch):
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     rows = kernel_bench.bench_rows(
         smoke=True, reps=1, shapes=[("tiny", 2, 4, 2, 16, 8, 2)])
     assert len(rows) == 5                           # trimmed candidate grid
     for r in rows:
-        assert r["interpret"] is True               # CPU host
+        assert r["interpret"] is True               # asked for above
         assert r["tokens_per_s"] > 0
         # interpreter timings sit far off the roofline; the rounded
         # figure may be 0.00 but can never exceed the bound
         assert 0 <= r["pct_of_roofline"] <= 100
     assert kernel_bench.winners(rows)               # one winner per key
+
+
+# ---------------------------------------------------------------------------
+# no silent interpreter: without a TPU the interpreter must be asked for
+# ---------------------------------------------------------------------------
+
+def test_bench_rows_refuses_cpu_without_interpret(monkeypatch):
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    with pytest.raises(RuntimeError, match="no TPU backend"):
+        kernel_bench.bench_rows(smoke=True, reps=1,
+                                shapes=[("tiny", 2, 4, 2, 16, 8, 2)])
+
+
+def test_run_kernel_ab_refuses_cpu_without_interpret(monkeypatch):
+    from benchmarks.common import run_kernel_ab
+    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
+    with pytest.raises(RuntimeError, match="no TPU backend"):
+        run_kernel_ab()
+
+
+def test_interpret_override_is_an_error_on_tpu(monkeypatch):
+    """REPRO_PALLAS_INTERPRET=1 may not swap a TPU's kernels for the
+    interpreter; off a TPU it is the explicit request."""
+    import jax
+    from repro.kernels import env_interpret
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
+    assert env_interpret(False) is True
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="TPU backend"):
+        env_interpret(False)

@@ -1,0 +1,135 @@
+"""Compile the serving path for a described TPU v5e at qwen3-1.7b's
+published widths — the Pallas kernels the engine calls, with the dtypes
+it passes (bf16 queries, the float32 page pool as stored), and the
+full-width decode and prefill steps, which must fit one chip's 16 GiB.
+
+Nothing runs: the TPU compiler installed beside JAX compiles for a chip
+that is described, not attached, so a block shape, tiling or memory
+failure shows here before any chip time is spent.  The topology is
+described inside a fixture (only the worker that runs this file loads
+the TPU library); where it cannot be described, the tests skip.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.engine.models import build_model
+
+H, HKV, DH = 16, 8, 128                  # qwen3-1.7b attention widths
+PAGE, POOL_PAGES = 8, 1024               # the engine's page size and pool
+B, N_PAGES = 8, 16                       # a full decode batch, 128 tokens
+HBM_LIMIT = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:       # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        # an entry compiled for a described chip cannot be read back
+        # without one; keep these compiles out of any persistent cache
+        enabled = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _spec(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args, **jit_kw):
+    return jax.jit(fn, **jit_kw).lower(*args).compile()
+
+
+def test_flash_attention_prefill_bucket(one_chip):
+    from repro.kernels.flash_attention.ops import flash_attention
+    S = lambda *a: _spec(one_chip, *a)
+    sq, skv = 48, 96                     # a chunk-prefill bucket
+    _compile(lambda q, k, v, qp, kp: flash_attention(
+        q, k, v, q_positions=qp, kv_positions=kp),
+        S((1, sq, H, DH), jnp.bfloat16), S((1, skv, HKV, DH), jnp.bfloat16),
+        S((1, skv, HKV, DH), jnp.bfloat16), S((1, sq), jnp.int32),
+        S((1, skv), jnp.int32))
+
+
+def test_decode_attention(one_chip):
+    from repro.kernels.decode_attention.ops import decode_attention
+    S = lambda *a: _spec(one_chip, *a)
+    T = N_PAGES * PAGE
+    _compile(lambda q, k, v, qp, kp: decode_attention(
+        q, k, v, q_positions=qp, kv_positions=kp),
+        S((B, 1, H, DH), jnp.bfloat16), S((B, T, HKV, DH), jnp.bfloat16),
+        S((B, T, HKV, DH), jnp.bfloat16), S((B, 1), jnp.int32),
+        S((B, T), jnp.int32))
+
+
+@pytest.mark.parametrize("variant", ["single", "blocked", "fused"])
+def test_paged_decode_attention(one_chip, variant):
+    from repro.kernels.paged_decode_attention.ops import (
+        fused_paged_decode_attention, paged_decode_attention)
+    S = lambda *a: _spec(one_chip, *a)
+    q = S((B, 1, H, DH), jnp.bfloat16)
+    pool = S((POOL_PAGES, PAGE, HKV, DH), jnp.float32)
+    pt, lens = S((B, N_PAGES), jnp.int32), S((B,), jnp.int32)
+    if variant == "fused":
+        new = S((B, HKV, DH), jnp.float32)
+        _compile(lambda *a: fused_paged_decode_attention(*a)[0],
+                 q, pool, pool, pt, lens, new, new)
+    else:
+        _compile(lambda *a: paged_decode_attention(*a, variant=variant),
+                 q, pool, pool, pt, lens)
+
+
+def _model_and_params(one_chip, impl):
+    model = build_model(get_config("qwen3-1.7b").replace(attention_impl=impl))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    return model, jax.tree.map(
+        lambda a: _spec(one_chip, a.shape, a.dtype), shapes)
+
+
+def _device_bytes(compiled) -> int:
+    m = compiled.memory_analysis()
+    return (m.argument_size_in_bytes + m.output_size_in_bytes
+            + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_full_width_decode_step(one_chip, impl):
+    """The engine's paged decode step, pool donated as on the chip."""
+    model, params = _model_and_params(one_chip, impl)
+    S = lambda *a: _spec(one_chip, *a)
+    layers, kv_heads, head_dim = model.paged_kv_layout()
+    pool = S((layers, POOL_PAGES, PAGE, kv_heads, head_dim), jnp.float32)
+    compiled = _compile(
+        lambda p, tok, kp, vp, pt, ln: model.paged_decode_step(
+            p, tok, kp, vp, pt, ln),
+        params, S((B,), jnp.int32), pool, pool, S((B, N_PAGES), jnp.int32),
+        S((B,), jnp.int32), donate_argnums=(2, 3))
+    assert _device_bytes(compiled) < HBM_LIMIT
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_full_width_prefill_step(one_chip, impl):
+    """The engine's chunk prefill over a cache view (cold and shared
+    prompts both take it)."""
+    model, params = _model_and_params(one_chip, impl)
+    S = lambda *a: _spec(one_chip, *a)
+    layers, kv_heads, head_dim = model.paged_kv_layout()
+    view = S((layers, 1, 128, kv_heads, head_dim), jnp.bfloat16)
+    cache = {"k": view, "v": view, "length": S((1,), jnp.int32)}
+    compiled = _compile(
+        lambda p, toks, c, n: model.prefill_with_cache(p, toks, c,
+                                                       valid_len=n),
+        params, S((1, 48), jnp.int32), cache, S((1,), jnp.int32))
+    assert _device_bytes(compiled) < HBM_LIMIT
+
