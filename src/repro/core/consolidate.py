@@ -29,6 +29,7 @@ import re
 from dataclasses import dataclass, field
 from typing import (Dict, FrozenSet, List, Optional, Sequence, Set, Tuple)
 
+from repro import obs
 from repro.core.graphspec import GraphSpec, NodeSpec
 from repro.core.parser import static_signature
 
@@ -425,7 +426,8 @@ class MultiConsolidatedGraph(ConsolidatedGraph):
 def consolidate(template: GraphSpec,
                 bindings: Sequence[Dict[str, str]]) -> ConsolidatedGraph:
     """One template × N bindings → one consolidated graph."""
-    return ConsolidatedGraph(template, bindings)
+    with obs.span("planner.consolidate"):
+        return ConsolidatedGraph(template, bindings)
 
 
 def consolidate_multi(batches: Sequence[Tuple[GraphSpec,

@@ -21,6 +21,7 @@ import time
 from dataclasses import dataclass
 from typing import Dict, FrozenSet, List, Optional, Tuple
 
+from repro import obs
 from repro.core.cost_model import CostModel
 from repro.core.graphspec import LLMDag
 from repro.core.plan import Epoch, ExecutionPlan
@@ -165,7 +166,7 @@ class EpochDPSolver:
     # ------------------------------------------------------------------
     def solve(self, initial: Optional[SystemState] = None) -> ExecutionPlan:
         """Solve from ``initial`` (or cold start) and rebuild the plan."""
-        t0 = time.perf_counter()
+        t0 = time.perf_counter_ns()
         state = initial or SystemState.initial(self.cfg.num_workers)
         start_done = state.done
         total, _ = self._solve(state)
@@ -178,6 +179,8 @@ class EpochDPSolver:
             plan.epochs.append(Epoch([list(c) for c in comps],
                                      list(workers), c_now))
             state = nxt
-        plan.solver_seconds = time.perf_counter() - t0
+        t1 = time.perf_counter_ns()
+        plan.solver_seconds = (t1 - t0) / 1e9
+        obs.add("planner.solve", t0, t1)
         plan.validate(self.dag, start_done)
         return plan

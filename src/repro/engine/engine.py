@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.debugsync import named_condition, named_lock
 from repro.engine.kvcache import PagedKVCache
@@ -100,10 +101,12 @@ class EngineStats:
     prefill_tokens: int = 0
     prefill_tokens_saved: int = 0        # tokens served from shared pages
     decode_tokens: int = 0
-    batches: int = 0                     # generate() calls
+    decode_steps: int = 0
+    decode_kv_tokens: int = 0            # live KV tokens read, summed over steps
+    pool_pages_used: int = 0             # pages not free, summed over steps
+    pool_pages_total: int = 0            # num_pages, summed over steps
+    admit_deferrals: int = 0             # admissions deferred for pages
     coalesced_requests: int = 0
-    model_loads: int = 0
-    load_seconds: float = 0.0
     prefix_hits: int = 0
     admission_waves: int = 0             # scheduler passes that admitted >=1
     priority_jumps: int = 0              # admissions that bypassed FIFO order
@@ -202,6 +205,8 @@ class _Request:
     temperature: float
     handle: RequestHandle
     priority: int = 0                    # SLO lane (DESIGN.md §10.3)
+    t_submit: int = 0                    # perf_counter_ns at submit
+    parent: Any = None                   # the submitter's span (obs)
 
 
 @dataclass
@@ -266,26 +271,36 @@ class InferenceEngine:
         self.num_pages = num_pages or max(
             64, 2 * max_batch * -(-max_seq_len // page_size))
         self.kv: Optional[PagedKVCache] = None   # guarded-by: self._cv | engine-loop
-        # jitted steps (cached per input/cache shape signature)
+        # jitted steps (cached per input/cache shape signature), named so
+        # the device trace's program line reads jit_<name>
+        model = self.model
+
+        def decode_step(p, tok, cache):
+            return model.decode_step(p, tok, cache)
+
+        def prefill(p, toks):
+            return model.prefill(p, toks)
+
+        def chunk_prefill(p, toks, cache, n):
+            return model.prefill_with_cache(p, toks, cache, valid_len=n)
+
+        def paged_decode_step(p, tok, kp, vp, pt, ln):
+            return model.paged_decode_step(p, tok, kp, vp, pt, ln,
+                                           variant=self.kernel_variant)
+
         # jit-ok: cache/toks shapes ARE the bucketing keys (view pad, _round_t)
-        self._decode_jit = jax.jit(
-            lambda p, tok, cache: self.model.decode_step(p, tok, cache))
+        self._decode_jit = jax.jit(decode_step)
         # jit-ok: cold prefill; toks already padded to _PF_QUANTUM buckets
-        self._prefill_jit = jax.jit(
-            lambda p, toks: self.model.prefill(p, toks))
+        self._prefill_jit = jax.jit(prefill)
         if self._paged_layout:
             # jit-ok: suffix chunks arrive _round_t-bucketed; n is traced
-            self._chunk_prefill_jit = jax.jit(
-                lambda p, toks, cache, n: self.model.prefill_with_cache(
-                    p, toks, cache, valid_len=n))
+            self._chunk_prefill_jit = jax.jit(chunk_prefill)
         if self._use_paged:
             # the pool arrays flow through the step; donating them lets
             # XLA scatter in place on device backends (CPU ignores it)
             donate = (2, 3) if jax.default_backend() != "cpu" else ()
-            self._paged_step_jit = jax.jit(
-                lambda p, tok, kp, vp, pt, ln: self.model.paged_decode_step(
-                    p, tok, kp, vp, pt, ln, variant=self.kernel_variant),
-                donate_argnums=donate)
+            self._paged_step_jit = jax.jit(paged_decode_step,
+                                           donate_argnums=donate)
         # scheduler state — owned by the loop thread ("engine-loop"),
         # shared with submitters/importers under _cv (DESIGN.md §11)
         self._cv = named_condition("InferenceEngine._cv")
@@ -300,7 +315,7 @@ class InferenceEngine:
         self._shutdown = False           # guarded-by: self._cv
         self._rid = 0                    # guarded-by: self._cv
         self._zero_key = jax.random.PRNGKey(0)
-        self._last_submit = 0.0          # guarded-by: self._cv
+        self._last_submit = 0            # guarded-by: self._cv
 
     # ---------------------------------------------------------------- weights
     def load(self) -> float:
@@ -314,10 +329,7 @@ class InferenceEngine:
             self.cfg.replace(name="", attention_impl="xla"),
             jax.random.PRNGKey(self.seed))
         jax.block_until_ready(jax.tree.leaves(self.params)[0])
-        dt = time.perf_counter() - t0
-        self.stats.model_loads += 1
-        self.stats.load_seconds += dt
-        return dt
+        return time.perf_counter() - t0
 
     def unload(self) -> None:
         """Drain in-flight work, then drop params, pages and warm prefixes."""
@@ -352,7 +364,7 @@ class InferenceEngine:
     def submit(self, prompt: Sequence[int], *, max_new_tokens: int = 16,
                temperature: float = 0.0,
                extra: Optional[Dict[str, Any]] = None,
-               priority: int = 0) -> RequestHandle:
+               priority: int = 0, parent=None) -> RequestHandle:
         """Enqueue one request into the persistent engine loop.
 
         Returns immediately; the request joins the running decode batch at
@@ -362,7 +374,9 @@ class InferenceEngine:
         lane), so an interactive request preempts batch-lane admission —
         including under KV-pool pressure, where a deferred interactive
         request holds the pass rather than letting batch work slip past
-        it.  All-equal priorities reduce exactly to FIFO.
+        it.  All-equal priorities reduce exactly to FIFO.  ``parent`` is
+        the submitter's span (``repro.obs``): the request's
+        ``engine.queue`` span, submit to slot, is its child.
         """
         if not self._paged_layout \
                 and len(prompt) + max_new_tokens > self.max_seq_len:
@@ -374,11 +388,12 @@ class InferenceEngine:
             if self._shutdown:
                 raise EngineError("engine is shut down")
             self._rid += 1
+            self._last_submit = time.perf_counter_ns()
             req = _Request(self._rid, tuple(int(t) for t in prompt),
                            dict(extra or {}), max_new_tokens, temperature,
-                           RequestHandle(self._rid), priority=int(priority))
+                           RequestHandle(self._rid), priority=int(priority),
+                           t_submit=self._last_submit, parent=parent)
             self._pending.append(req)
-            self._last_submit = time.monotonic()
             self._ensure_loop()
             self._cv.notify_all()
         return req.handle
@@ -398,7 +413,6 @@ class InferenceEngine:
         handles = [self.submit(p, max_new_tokens=max_new_tokens,
                                temperature=temperature, extra=e)
                    for p, e in zip(prompts, extras)]
-        self.stats.batches += 1
         return [h.result() for h in handles]
 
     # requires: self._cv
@@ -633,10 +647,11 @@ class InferenceEngine:
 
     def _step(self) -> None:
         """One scheduler iteration: admit, then one decode step."""
-        self._grace_window()
-        self._admit()
-        if self._active:
-            self._decode_once()
+        with obs.span("engine.step"):
+            self._grace_window()
+            self._admit()
+            if self._active:
+                self._decode_once()
 
     def _grace_window(self) -> None:
         """Hold a FRESH batch's admission until ``admission_window``
@@ -647,18 +662,25 @@ class InferenceEngine:
         w = self.admission_window
         if w <= 0 or self._active:
             return
-        cap = time.monotonic() + 10 * w
+        cap = time.perf_counter() + 10 * w
         with self._cv:
             while not self._shutdown and self._pending:
-                now = time.monotonic()
-                wait = self._last_submit + w - now
+                now = time.perf_counter()
+                wait = self._last_submit / 1e9 + w - now
                 if wait <= 0 or now >= cap:
                     break
                 self._cv.wait(timeout=min(wait, cap - now))
 
     # ------------------------------------------------------------- admission
     def _admit(self) -> None:
-        admitted = 0
+        with obs.span("engine.admit") as sp:
+            admitted, deferred = self._admit_pass()
+            sp.set(admitted=admitted, deferred=deferred)
+
+    def _admit_pass(self):
+        """Admit waiting requests while slots are free; (admitted,
+        deferred) for the pass."""
+        admitted = deferred = 0
         while len(self._active) < self.max_batch:
             with self._cv:
                 if not self._pending:
@@ -680,6 +702,8 @@ class InferenceEngine:
                 # request blocks the whole pass, so lower-priority work
                 # can never be admitted around a waiting interactive
                 # request (it preempts batch, never vice versa)
+                deferred = 1
+                self.stats.admit_deferrals += 1
                 break
             except BaseException as e:                  # per-request failure
                 self._remove_pending(req)
@@ -703,6 +727,13 @@ class InferenceEngine:
             self.stats.peak_batch = max(self.stats.peak_batch,
                                         len(self._active))
             self._dirty = True
+        return admitted, deferred
+
+    @staticmethod
+    def _queue_done(req: _Request, t1: Optional[int] = None) -> None:
+        """The request's wait for a slot (or a leader) ended at ``t1``."""
+        obs.add("engine.queue", req.t_submit, t1, parent=req.parent,
+                rid=req.rid)
 
     @staticmethod
     def _duplicates(a: _Request, b: _Request) -> bool:
@@ -724,6 +755,7 @@ class InferenceEngine:
             if self._duplicates(s.req, req):
                 s.followers.append(req.handle)
                 self.stats.coalesced_requests += 1
+                self._queue_done(req)
                 return True
         return False
 
@@ -739,6 +771,7 @@ class InferenceEngine:
             for r in self._pending:
                 if r is not req and self._duplicates(r, req):
                     out.append(r.handle)
+                    self._queue_done(r)
                 else:
                     kept.append(r)
             self._pending = kept
@@ -830,11 +863,10 @@ class InferenceEngine:
         slot = _Slot(req=req, remaining=req.max_new,
                      rng=self._request_rng(req))
         shareable = (self.enable_prefix_sharing and not req.extra and S > 1)
-
+        donor = None
+        shared = 0
         if self._paged_layout:
             kv = self._ensure_kv()
-            donor = None
-            shared = 0
             if shareable:
                 # deepest-first fallback; cap at S-1 so one fresh token
                 # remains to decode from
@@ -844,6 +876,23 @@ class InferenceEngine:
                 fresh_tokens += req.extra["patch_embeds"].shape[-2]
             self._ensure_pages(-(-fresh_tokens // self.page_size) + 1,
                                protect=donor)
+        # past _ensure_pages the request holds its slot: its queue ends
+        with obs.span("engine.prefill", rid=req.rid, prompt_tokens=S,
+                      shared_tokens=shared) as sp:
+            self._queue_done(req, sp.t0)
+            logits = self._prefill_into(slot, donor, shared, shareable)
+            if req.max_new > 0:
+                self._emit_token(slot, logits[0:1])
+        return slot
+
+    def _prefill_into(self, slot: _Slot, donor: Optional[int], shared: int,
+                      shareable: bool):
+        """Prefill ``slot``'s prompt into its cache (the donor's first
+        ``shared`` tokens aliased); the last position's logits."""
+        req = slot.req
+        S = len(req.prompt)
+        if self._paged_layout:
+            kv = self.kv
             if donor is not None:
                 logits = self._prefill_shared(slot, donor, shared)
                 self.stats.prefix_hits += 1
@@ -881,10 +930,7 @@ class InferenceEngine:
                                                self.max_seq_len - t_cur)
             slot.length = S
             self.stats.prefill_tokens += S
-
-        if req.max_new > 0:
-            self._emit_token(slot, logits[0:1])
-        return slot
+        return logits
 
     def _prefill_cold(self, slot: _Slot):
         """Prefill a donor-less prompt via the bucketed chunk step over
@@ -1007,9 +1053,17 @@ class InferenceEngine:
                 for k, v in view.items()}
 
     def _decode_once(self) -> None:
-        if self._use_paged:
-            self._decode_paged()
-            return
+        """One decode step over every active slot: host preparation and
+        dispatch (span ``engine.decode``), then sampling and the sync."""
+        b_real = len(self._active)
+        with obs.span("engine.decode", rows=b_real) as sp:
+            logits = (self._decode_paged(sp) if self._use_paged
+                      else self._decode_dense())
+        self.stats.decode_steps += 1
+        self.stats.decode_tokens += b_real
+        self._advance(logits)
+
+    def _decode_dense(self):
         if self._dirty:
             self._rebuild_view()
             for i, s in enumerate(self._active):
@@ -1034,15 +1088,17 @@ class InferenceEngine:
             self.kv.append_tokens([s.seq_id for s in slots], k_taps, v_taps)
         for s in slots:
             s.length += 1
-        self.stats.decode_tokens += b_real
-        self._advance(logits)
+        return logits
 
-    def _decode_paged(self) -> None:
+    def _decode_paged(self, sp):
         """One decode step straight over the device-resident page pool:
         upload O(batch) metadata (tokens, page tables, lengths), run the
-        paged step (in-pool KV scatter + paged attention), download
-        O(batch) sampled ids.  No dense view exists, so composition
-        changes are free — no ``_rebuild_view``, no KV tap sync."""
+        paged step (in-pool KV scatter + paged attention), and return its
+        logits; O(batch) sampled ids come back in ``_advance``.  No dense
+        view exists, so composition changes are free — no
+        ``_rebuild_view``, no KV tap sync.  Counts the live KV tokens the
+        step reads and the pool's pages in use onto ``sp`` and the
+        stats."""
         kv = self.kv
         slots = self._active
         b_real = len(slots)
@@ -1051,6 +1107,12 @@ class InferenceEngine:
         # safety contract (the step derives (page, offset) from the
         # uploaded table, so the returned targets aren't re-shipped)
         kv.prepare_appends([s.seq_id for s in slots])
+        live = sum(s.length + 1 for s in slots)     # the new token's too
+        used = kv.num_pages - len(kv.free_pages)
+        self.stats.decode_kv_tokens += live
+        self.stats.pool_pages_used += used
+        self.stats.pool_pages_total += kv.num_pages
+        sp.set(kv_tokens=live, pages_used=used, pages_total=kv.num_pages)
         b_pad = self._round_b(b_real)
         # pad like the dense view's quanta so recompiles stay bounded
         t_cap = self._round_t(max(s.length + s.remaining for s in slots))
@@ -1071,8 +1133,7 @@ class InferenceEngine:
         kv.commit_appends([s.seq_id for s in slots])
         for s in slots:
             s.length += 1
-        self.stats.decode_tokens += b_real
-        self._advance(logits)
+        return logits
 
     def _emit_token(self, slot: _Slot, logits) -> None:
         """Sample one token for ``slot`` from (1, Vpad) logits."""
@@ -1109,7 +1170,8 @@ class InferenceEngine:
         toks_dev, new_keys = _batched_sample(
             logits, keys, jnp.asarray(temps_pad),
             vocab_size=self.cfg.vocab_size)
-        toks = np.asarray(toks_dev)      # one O(batch)-ints sync per step
+        with obs.span("engine.sync"):
+            toks = np.asarray(toks_dev)  # one O(batch)-ints sync per step
         self.stats.d2h_bytes += toks.nbytes
         finished = []
         for i, s in enumerate(slots):

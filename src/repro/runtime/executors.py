@@ -22,6 +22,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Sequence
 
+from repro import obs
 from repro.configs.base import ModelConfig
 from repro.core.coalesce import CoalesceTable
 from repro.core.graphspec import GraphSpec
@@ -55,7 +56,6 @@ class EngineHost:
         # go through engine_for_import, which never switches residency
         self.resident: Optional[str] = None             # guarded-by: gpu-worker
         self.switches = 0                               # guarded-by: gpu-worker
-        self.switch_seconds = 0.0                       # guarded-by: gpu-worker
         # node -> recent prompt token tuples served here; the KVMigrator
         # reads this to know WHICH warm prefixes a replan strands when it
         # moves the node to another worker.  Persists with the host
@@ -77,12 +77,13 @@ class EngineHost:
         if self.resident != model:
             prev = (self.peek_engine(self.resident)
                     if self.resident is not None else None)
-            if prev is not None:
-                # unload/load run OUTSIDE _engines_lock: they move real
-                # params and must not block the migrator's peek
-                prev.unload()
-                self.switches += 1
-            self.switch_seconds += eng.load()
+            with obs.span("runtime.switch", model=model):
+                if prev is not None:
+                    # unload/load run OUTSIDE _engines_lock: they move
+                    # real params and must not block the migrator's peek
+                    prev.unload()
+                    self.switches += 1
+                eng.load()
             self.resident = model
         return eng
 
@@ -275,8 +276,11 @@ class GPUWorkerThread(threading.Thread):
             # engine-busy time, whereas one node-wide record would count
             # the gaps spent waiting for later queries' deps as GPU work
             # (inflating overlap and poisoning calibration samples)
+            t_wave = time.perf_counter_ns()
+            wave_span = obs.begin("runtime.wave", t0=t_wave, node=nid,
+                                  worker=self.wid, rows=len(wave))
             wave_track = {"done": 0, "expected": len(wave),
-                          "start": time.perf_counter() - self.t0}
+                          "start": t_wave / 1e9 - self.t0, "span": wave_span}
             wave_prompts = []
             for q in wave:
                 text = render(spec.prompt, self.bindings[q],
@@ -286,7 +290,8 @@ class GPUWorkerThread(threading.Thread):
                 h = eng.submit(toks,
                                max_new_tokens=spec.max_new_tokens,
                                temperature=spec.temperature,
-                               priority=state.priority_of(q))
+                               priority=state.priority_of(q),
+                               parent=wave_span)
                 h.add_done_callback(
                     self._on_request_done(nid, q, node_track, wave_track,
                                           tlock))
@@ -310,12 +315,14 @@ class GPUWorkerThread(threading.Thread):
             return []
         if self.host.engine_kwargs.get("admission_window", 0) > 0:
             return sorted(ready)         # engine-side window batches these
-        for _ in range(10):
-            time.sleep(0.002)
-            grown = {q for q in pending if self.state.query_ready(q, nid)}
-            if grown == ready:
-                break
-            ready = grown
+        with obs.span("runtime.settle", node=nid):
+            for _ in range(10):
+                time.sleep(0.002)
+                grown = {q for q in pending
+                         if self.state.query_ready(q, nid)}
+                if grown == ready:
+                    break
+                ready = grown
         return sorted(ready)
 
     # runs-on: any
@@ -339,13 +346,15 @@ class GPUWorkerThread(threading.Thread):
             self._fail(err)
             return
         toks = h.result(timeout=1.0)
-        te = time.perf_counter() - self.t0
+        t_end = time.perf_counter_ns()
+        te = t_end / 1e9 - self.t0
         with tlock:
             wave_track["done"] += 1
             node_track["done"] += 1
             wave_done = wave_track["done"] == wave_track["expected"]
             node_done = node_track["done"] == node_track["expected"]
         if wave_done:                     # record before the final publish
+            obs.end(wave_track["span"], t_end)
             ts = wave_track["start"]
             with self.records_lock:
                 self.records.append(TaskRecord(
@@ -518,11 +527,12 @@ class ToolDispatcher(threading.Thread):
     def _execute(self, sig: str, op: str, args: str, origin: str,
                  attempt: int = 1) -> None:
         try:
-            ts = time.perf_counter() - self.t0
+            t_start = time.perf_counter_ns()
             if self.faults is not None:
                 self.faults.tool_call(sig, op)
             result, _ = self.tools.execute(op, args)
-            te = time.perf_counter() - self.t0
+            t_end = time.perf_counter_ns()
+            ts, te = t_start / 1e9 - self.t0, t_end / 1e9 - self.t0
         except TransientToolError as e:
             # bounded retry: transient (injected or real network-blip
             # style) failures re-enter the pool instead of killing the
@@ -559,6 +569,7 @@ class ToolDispatcher(threading.Thread):
                 self.records.append(TaskRecord(
                     node=origin, kind="tool", worker="cpu", start=ts,
                     end=te, batch=max(len(requesters), 1), info=op))
+            obs.add("runtime.tool", t_start, t_end, op=op)
             if self.optimizer is not None:
                 self.optimizer.observe_tool(origin, op, te - ts)
             for q, nid in requesters:
