@@ -350,9 +350,11 @@ class InferenceEngine:
         return self.cfg.param_count() * 2          # bf16
 
     def hbm_bytes(self) -> int:
-        """Device bytes this engine holds at its decode peak once loaded:
-        the params, the KV pool, and the pool-sized copy the paged decode
-        step's layer scan writes (its compiled temporaries)."""
+        """Device bytes this engine holds at its peak once loaded: the
+        params, the KV pool, and a second pool.  The paged decode step
+        updates the donated pool in place, but ``PagedKVCache`` writes
+        prefilled and copy-on-write pages with eager ``.at[].set``, which
+        builds a new pool while the old one is still alive."""
         if not self._paged_layout:
             return self.param_bytes()
         layers, kv_heads, head_dim = self._paged_layout

@@ -495,9 +495,11 @@ class TransformerLM:
         scatter dispatch, no extra pool round-trip per layer);
         ``single``/``blocked`` scatter first, then attend.  The XLA
         fallback scatters and gathers densely.  Padded rows write
-        nothing and are fully masked.  Returns ``(logits (B, Vpad),
-        new_k_pages, new_v_pages)``; the caller adopts the returned
-        pool arrays (donated under jit).
+        nothing and are fully masked.  The whole pool is the layer
+        scan's carry, so under donation every layer's write lands in
+        the donated buffer: no pool-sized temporary, no copy back.
+        Returns ``(logits (B, Vpad), new_k_pages, new_v_pages)``; the
+        caller adopts the returned pool arrays (donated under jit).
         """
         cfg = self.cfg
         impl = impl or cfg.attention_impl
@@ -524,7 +526,8 @@ class TransformerLM:
                                cfg.num_heads // cfg.num_kv_heads)
             eff_variant = variant or kc["variant"]
 
-        def step_block(p, x, kp_l, vp_l):
+        def step_block(p, x, kp, vp, l):
+            # kp/vp: the whole pool; l: this layer's index in it
             h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
             q, k, v = L.attn_qkv(p["attn"], h, num_heads=cfg.num_heads,
                                  num_kv_heads=cfg.num_kv_heads,
@@ -534,63 +537,61 @@ class TransformerLM:
                                  norm_eps=cfg.norm_eps)
             if use_pallas and eff_variant == "fused":
                 # append+attend in ONE dispatch: the kernel writes the
-                # new KV rows into their (page, offset) slots before any
-                # page is read, so the scatter below never runs
+                # new KV rows into their (page, offset) slots of its
+                # layer before any page is read; the layer goes back in
                 o, kp_l, vp_l = fused_paged_decode_attention(
-                    q, kp_l, vp_l, page_table, pos,
-                    k[:, 0].astype(kp_l.dtype), v[:, 0].astype(vp_l.dtype),
+                    q, lax.dynamic_index_in_dim(kp, l, keepdims=False),
+                    lax.dynamic_index_in_dim(vp, l, keepdims=False),
+                    page_table, pos,
+                    k[:, 0].astype(kp.dtype), v[:, 0].astype(vp.dtype),
                     interpret=impl == "pallas_interpret")
-                x = x + L.attn_out(p["attn"], o)
-                h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-                if "moe" in p:
-                    y, _ = M.moe_ffn(h, p["moe"], cfg.moe)
-                else:
-                    y = L.ffn_apply(p["ffn"], h)
-                return x + y, kp_l, vp_l
-            kp_l = kp_l.at[write_page, write_off].set(
-                k[:, 0].astype(kp_l.dtype), mode="drop")
-            vp_l = vp_l.at[write_page, write_off].set(
-                v[:, 0].astype(vp_l.dtype), mode="drop")
-            if use_pallas:
-                # the kernels read the pool as stored and upcast per page;
-                # casting it here would copy the whole layer pool each step
-                o = paged_decode_attention(
-                    q, kp_l, vp_l, page_table, pos,
-                    interpret=impl == "pallas_interpret",
-                    variant=eff_variant)
+                kp = lax.dynamic_update_index_in_dim(kp, kp_l, l, 0)
+                vp = lax.dynamic_update_index_in_dim(vp, vp_l, l, 0)
             else:
-                kd = kp_l[page_table].reshape(
-                    B, T, cfg.num_kv_heads, self.head_dim).astype(self.dtype)
-                vd = vp_l[page_table].reshape(
-                    B, T, cfg.num_kv_heads, self.head_dim).astype(self.dtype)
-                o = L.attention(q, kd, vd, q_positions=posc[:, None],
-                                kv_positions=kv_pos, causal=True,
-                                window=cfg.swa_window, impl="xla")
+                kp = kp.at[l, write_page, write_off].set(
+                    k[:, 0].astype(kp.dtype), mode="drop")
+                vp = vp.at[l, write_page, write_off].set(
+                    v[:, 0].astype(vp.dtype), mode="drop")
+                if use_pallas:
+                    # the kernels read the pool as stored and upcast per
+                    # page; casting it here would copy the whole layer
+                    o = paged_decode_attention(
+                        q, lax.dynamic_index_in_dim(kp, l, keepdims=False),
+                        lax.dynamic_index_in_dim(vp, l, keepdims=False),
+                        page_table, pos,
+                        interpret=impl == "pallas_interpret",
+                        variant=eff_variant)
+                else:
+                    kd = kp[l, page_table].reshape(
+                        B, T, cfg.num_kv_heads, self.head_dim).astype(self.dtype)
+                    vd = vp[l, page_table].reshape(
+                        B, T, cfg.num_kv_heads, self.head_dim).astype(self.dtype)
+                    o = L.attention(q, kd, vd, q_positions=posc[:, None],
+                                    kv_positions=kv_pos, causal=True,
+                                    window=cfg.swa_window, impl="xla")
             x = x + L.attn_out(p["attn"], o)
             h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
             if "moe" in p:
                 y, _ = M.moe_ffn(h, p["moe"], cfg.moe)
             else:
                 y = L.ffn_apply(p["ffn"], h)
-            return x + y, kp_l, vp_l
+            return x + y, kp, vp
 
-        lead_k, lead_v = [], []
+        # the pool rides in the scan's carry and each layer updates it in
+        # place; as xs/ys it would be sliced per layer into a fresh
+        # pool-sized buffer and copied back over the donated one every step
+        ks, vs = k_pages, v_pages
         for i, p in enumerate(params.get("lead_blocks", [])):
-            x, kp_l, vp_l = step_block(p, x, k_pages[i], v_pages[i])
-            lead_k.append(kp_l)
-            lead_v.append(vp_l)
+            x, ks, vs = step_block(p, x, ks, vs, i)
 
-        def body(x, xs):
-            p, kp_l, vp_l = xs
-            x, kp_l, vp_l = step_block(p, x, kp_l, vp_l)
-            return x, (kp_l, vp_l)
+        def body(carry, xs):
+            p, l = xs
+            return step_block(p, *carry, l), None
 
-        x, (ks, vs) = lax.scan(
-            body, x, (params["blocks"],
-                      k_pages[self.n_lead:], v_pages[self.n_lead:]))
-        if lead_k:
-            ks = jnp.concatenate([jnp.stack(lead_k), ks], axis=0)
-            vs = jnp.concatenate([jnp.stack(lead_v), vs], axis=0)
+        (x, ks, vs), _ = lax.scan(
+            body, (x, ks, vs),
+            (params["blocks"],
+             jnp.arange(self.n_lead, cfg.num_layers, dtype=jnp.int32)))
 
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]

@@ -65,7 +65,7 @@ def workers_that_fit(models, requested: int) -> Tuple[int, str]:
     need = max(InferenceEngine(cfg).hbm_bytes() for cfg in models.values())
     n = max(1, min(requested, int(limit // need)))
     return n, (f"{n} worker(s): each needs {need / 1e9:.2f} GB (params + KV "
-               f"pool + decode-step pool copy), device limit "
+               f"pool + the pool copy of an eager page write), device limit "
                f"{limit / 1e9:.2f} GB")
 
 

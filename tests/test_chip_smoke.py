@@ -13,6 +13,8 @@ import pytest
 
 from repro.configs import SERVED_ARCH, get_config, get_smoke, models_for
 from repro.core import TPU_V5E, hardware_for_device_kind
+from repro.engine.engine import InferenceEngine
+from repro.engine.kvcache import PagedKVCache
 from repro.launch import serve
 from repro.workloads import build_workload
 
@@ -78,3 +80,23 @@ def test_workers_that_fit_without_a_memory_limit():
     graph, _, _ = build_workload("wt", 2)
     n, why = serve.workers_that_fit(models_for(graph, smoke=True), 2)
     assert n == 2 and "no memory limit" in why
+
+
+@pytest.mark.parametrize("cfg, gb", [(get_smoke("qwen3-1.7b"), None),
+                                     (get_smoke("deepseek-moe-16b"), None),
+                                     (get_config("qwen3-1.7b"), 7.20)],
+                         ids=["qwen3-smoke", "moe-smoke", "qwen3-full"])
+def test_engine_hbm_bytes_count_params_and_two_pools(cfg, gb):
+    """A paged engine holds its params, its KV pool and, while an eager
+    ``PagedKVCache`` page write builds the new pool, the old one too: so
+    ``workers_that_fit`` counts the pool twice.  Two full-width
+    qwen3-1.7b engines (2 x 7.20 GB) peaked at 13.56-14.49 GB on a
+    TPU v5e, and 16.91 GB holds two of them, not three."""
+    eng = InferenceEngine(cfg)
+    layers, kv_heads, head_dim = eng.model.paged_kv_layout()
+    pool = PagedKVCache.pool_bytes(layers, eng.num_pages, eng.page_size,
+                                   kv_heads, head_dim)
+    assert eng.hbm_bytes() == eng.param_bytes() + 2 * pool
+    if gb is not None:
+        assert round(eng.hbm_bytes() / 1e9, 2) == gb
+        assert int(16.91e9 // eng.hbm_bytes()) == 2

@@ -135,6 +135,66 @@ def test_paged_decode_step_kernel_matches_xla_gather():
                                atol=5e-2, rtol=5e-2)
 
 
+@pytest.mark.parametrize("name", ["qwen3-1.7b", "deepseek-moe-16b"])
+def test_chained_paged_decode_steps_match_dense_decode_step(name):
+    """Six chained ``paged_decode_step`` calls against the dense
+    ``decode_step`` over the same prompts: rows of 5, 7 and 20 tokens
+    cross page boundaries (page size 4), a fourth row is padded
+    (``lengths == -1``).  Logits agree, every written ``(page, offset)``
+    slot holds the dense cache's KV for that position, and nothing else
+    in the pool changes, so the padded row wrote nothing.  The MoE
+    config's first layer is a dense lead block, outside the layer scan."""
+    from repro.engine.models import build_model
+    model = build_model(get_smoke(name))
+    params = model.init(jax.random.PRNGKey(0))
+    layers, heads, dh = model.paged_kv_layout()
+    ps, n_pages, steps = 4, 7, 6
+    T = n_pages * ps
+    prompts = [list(range(3, 8)), list(range(30, 37)), list(range(50, 70))]
+    kv = PagedKVCache(layers, num_pages=16, page_size=ps, kv_heads=heads,
+                      head_dim=dh)
+    B = len(prompts) + 1                          # the last row is padding
+    k_rows = np.zeros((B, layers, T, heads, dh), np.float32)
+    v_rows = np.zeros_like(k_rows)
+    seqs, lengths, token = [], [], []
+    for b, prompt in enumerate(prompts):
+        logits, cache = model.prefill(params, jnp.asarray([prompt], jnp.int32))
+        k, v = model.cache_kv_rows_dev(cache, 0, len(prompt))
+        seqs.append(kv.add_sequence(k, v))
+        k_rows[b, :, :len(prompt)] = np.asarray(k, np.float32)
+        v_rows[b, :, :len(prompt)] = np.asarray(v, np.float32)
+        lengths.append(len(prompt))
+        token.append(int(jnp.argmax(logits[0])))
+    dense = model.paged_cache_view(k_rows, v_rows, lengths + [0])
+    paged_step = jax.jit(model.paged_decode_step)
+    dense_step = jax.jit(model.decode_step)
+    k_want, v_want = np.array(kv.k), np.array(kv.v)
+    for _ in range(steps):
+        slots = [kv.prepare_append(s) for s in seqs]
+        pt = np.zeros((B, n_pages), np.int32)
+        for b, s in enumerate(seqs):
+            pages = kv.page_table(s)
+            pt[b, :len(pages)] = pages
+        tok = jnp.asarray(token + [0], jnp.int32)
+        lg_p, kv.k, kv.v = paged_step(params, tok, kv.k, kv.v,
+                                      jnp.asarray(pt),
+                                      jnp.asarray(lengths + [-1], jnp.int32))
+        lg_d, dense = dense_step(params, tok, dense)
+        np.testing.assert_array_equal(np.asarray(lg_p[:-1], np.float32),
+                                      np.asarray(lg_d[:-1], np.float32))
+        k_tap, v_tap = model.decode_kv_taps(dense, lengths + [0])
+        for b, (page, off) in enumerate(slots):
+            k_want[:, page, off] = k_tap[:, b]
+            v_want[:, page, off] = v_tap[:, b]
+        np.testing.assert_array_equal(np.asarray(kv.k), k_want)
+        np.testing.assert_array_equal(np.asarray(kv.v), v_want)
+        for s in seqs:
+            kv.commit_append(s)
+        lengths = [n + 1 for n in lengths]
+        token = [int(t) for t in jnp.argmax(lg_d[:-1], axis=-1)]
+    assert min(lengths) == 11 and max(lengths) == 26
+
+
 def test_engine_kernel_variants_bitwise_identical():
     """The full engine under each Pallas paged-decode kernel variant —
     single-page, multi-page blocked, and fused append+attend (which

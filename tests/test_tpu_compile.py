@@ -9,6 +9,10 @@ failure shows here before any chip time is spent.  The topology is
 described inside a fixture (only the worker that runs this file loads
 the TPU library); where it cannot be described, the tests skip.
 """
+import functools
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -103,19 +107,44 @@ def _device_bytes(compiled) -> int:
             + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
-@pytest.mark.parametrize("impl", ["xla", "pallas"])
-def test_full_width_decode_step(one_chip, impl):
-    """The engine's paged decode step, pool donated as on the chip."""
+@functools.lru_cache(maxsize=None)
+def _decode_step(one_chip, impl):
+    """The engine's paged decode step at full width, pool donated as on
+    the chip; returns (compiled, the pool's shape)."""
     model, params = _model_and_params(one_chip, impl)
     S = lambda *a: _spec(one_chip, *a)
     layers, kv_heads, head_dim = model.paged_kv_layout()
-    pool = S((layers, POOL_PAGES, PAGE, kv_heads, head_dim), jnp.float32)
+    shape = (layers, POOL_PAGES, PAGE, kv_heads, head_dim)
+    pool = S(shape, jnp.float32)
     compiled = _compile(
         lambda p, tok, kp, vp, pt, ln: model.paged_decode_step(
             p, tok, kp, vp, pt, ln),
         params, S((B,), jnp.int32), pool, pool, S((B, N_PAGES), jnp.int32),
         S((B,), jnp.int32), donate_argnums=(2, 3))
+    return compiled, shape
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_full_width_decode_step(one_chip, impl):
+    compiled, _ = _decode_step(one_chip, impl)
     assert _device_bytes(compiled) < HBM_LIMIT
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas"])
+def test_full_width_decode_step_updates_pool_in_place(one_chip, impl):
+    """The layer scan updates the donated pool in place: no pool-sized
+    temporary (under one layer's K+V slice), both pools aliased to the
+    outputs, and no copy of the whole pool in the optimized program."""
+    compiled, shape = _decode_step(one_chip, impl)
+    layer_kv = 2 * math.prod(shape[1:]) * 4
+    pool = math.prod(shape) * 4
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < layer_kv
+    assert m.alias_size_in_bytes >= 2 * pool
+    hlo_shape = re.escape("f32[%s]" % ",".join(map(str, shape)))
+    copies = re.findall(rf"= {hlo_shape}(?:{{[^}}]*}})? copy(?:-start)?\(",
+                        compiled.as_text())
+    assert not copies
 
 
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
