@@ -6,7 +6,8 @@ control's widest gap over many seeds, in one process.
 
 Each seed is a whole run of the cell (its own weights, warm-up and a
 short window at the cell's own load) followed by the check; the control
-(the reference in float8, ``reference.py``) is scored on the same
+(the reference in float8, the configuration's ``arch/<model_type>.py``
+``logits_at``) is scored on the same
 requests and judged against the same limit: ``program_correct`` should
 read true and ``control_correct`` false.  One JSON line per seed; the
 benchmark's own runs never run the control.

@@ -8,8 +8,9 @@ Two comparisons, both after the window has closed:
    the tokens the engine served for that node's prompt.
 2. The model.  For a sample of the finished queries, drawn from the seed
    and holding the one with the longest prompts, every served token of
-   every LLM node is scored by the plain float32 reference
-   (``reference.py``) over the same prompt and served tokens:
+   every LLM node is scored by the plain float32 reference of the
+   configuration's architecture (``arch/<model_type>.py``
+   ``logits_at``) over the same prompt and served tokens:
    ``gap_sd`` is the widest distance, in standard deviations of that
    position's logits, by which a served token's reference logit lies
    below the reference's best.  Served tokens are greedy, so a faithful
@@ -134,18 +135,19 @@ def sample_queries(queries: Sequence[dict], k: int, seed: int) -> List[int]:
 
 
 # ------------------------------------------------------------ the model
-def model_gap(params, dims, calls, fp8: bool = False
+def model_gap(arch, params, dims, calls, fp8: bool = False
               ) -> Tuple[Optional[float], int, Optional[float]]:
     """(widest gap in standard deviations over every served token of
-    ``calls``, tokens scored, widest gap in raw logits).  With ``fp8``
-    the control: at each position the token the fp8 forward puts first
-    is scored by the float32 reference instead of the served token."""
-    from chipbench import reference as R
+    ``calls``, tokens scored, widest gap in raw logits), scored by
+    ``arch.logits_at``, the reference of the configuration's
+    architecture.  With ``fp8`` the control: at each position the token
+    the fp8 forward puts first is scored by the float32 reference
+    instead of the served token."""
     seqs = [(list(p) + list(t[:-1]), len(p) - 1) for p, t in calls]
     if not seqs:
         return None, 0, None
-    ref = R.logits_at(params, dims, seqs)
-    low = R.logits_at(params, dims, seqs, fp8=True) if fp8 else None
+    ref = arch.logits_at(params, dims, seqs)
+    low = arch.logits_at(params, dims, seqs, fp8=True) if fp8 else None
     worst, worst_raw, n = 0.0, 0.0, 0
     for j, (_, toks) in enumerate(calls):
         picked = (low[j].argmax(-1)[:len(toks)] if fp8 else

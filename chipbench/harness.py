@@ -1,7 +1,9 @@
 """One run of one cell: set-up, the measured window, the check.
 
 Everything a run reads comes from files found by name: the cell in
-``BENCHMARK.json``, its configuration file, its traffic mix
+``BENCHMARK.json``, its configuration file, the module of the
+configuration's architecture (``chipbench/arch/<model_type>.py``: its
+reference and work counts), its traffic mix
 (``chipbench/traffic/<traffic>.json``), the chip's peaks
 (``chipbench/peaks.json``) and one reader per metric
 (``chipbench/metrics/<metric>.py``).
@@ -15,7 +17,7 @@ import os
 import sys
 import threading
 import time
-from types import SimpleNamespace
+from types import ModuleType, SimpleNamespace
 from typing import Dict, List, Optional
 
 from chipbench import check, drivers, generate, program
@@ -33,6 +35,34 @@ class SetupError(RuntimeError):
 def load_json(path: str) -> dict:
     with open(path) as f:
         return json.load(f)
+
+
+def load_module(path: str, name: str) -> ModuleType:
+    """The Python file at ``path`` as a module named ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+_ARCHS: Dict[str, ModuleType] = {}
+
+
+def arch_of(conf: dict, root: str = ROOT) -> ModuleType:
+    """The module of a configuration's architecture,
+    ``<root>/chipbench/arch/<model_type>.py`` (each module's docstring
+    lists what it provides), loaded once per path so that its jitted
+    reference compiles once per process.  The metric readers find it
+    under the checkout they run from."""
+    path = os.path.join(root, "chipbench", "arch",
+                        f"{conf['model_type']}.py")
+    if path not in _ARCHS:
+        if not os.path.isfile(path):
+            raise SetupError(f"no module for architecture "
+                             f"{conf['model_type']!r} at {path}")
+        _ARCHS[path] = load_module(
+            path, f"chipbench_arch_{conf['model_type']}")
+    return _ARCHS[path]
 
 
 class Bench:
@@ -54,6 +84,9 @@ class Bench:
                 return load_json(os.path.join(self.root, c["file"]))
         raise SetupError(f"no configuration {name!r} in BENCHMARK.json")
 
+    def arch(self, conf: dict) -> ModuleType:
+        return arch_of(conf, self.root)
+
     def mix(self, traffic: str) -> dict:
         return load_json(os.path.join(self.root, "chipbench", "traffic",
                                       f"{traffic}.json"))
@@ -68,11 +101,8 @@ class Bench:
                             f"{metric}.py")
         if not os.path.isfile(path):
             raise SetupError(f"no reader for metric {metric!r} at {path}")
-        spec = importlib.util.spec_from_file_location(
-            f"chipbench_metric_{metric.replace('.', '_')}", path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return load_module(
+            path, f"chipbench_metric_{metric.replace('.', '_')}").read
 
 
 def peaks_for(kind: str, root: str = ROOT) -> dict:
@@ -193,7 +223,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     dev = devs[0]
     peak = None if rehearsal else peaks_for(dev.device_kind, bench.root)
     program.import_program(bench.root)
-    ctx = setup(conf, mix, seed, rehearsal, drive)
+    ctx = setup(conf, bench.arch(conf), mix, seed, rehearsal, drive)
     ctx.spans.tracing = trace
     tracer = None
     if trace:
@@ -238,13 +268,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
     return result
 
 
-def setup(conf: dict, mix: dict, seed: int, rehearsal: bool,
-          drive) -> SimpleNamespace:
+def setup(conf: dict, arch: ModuleType, mix: dict, seed: int,
+          rehearsal: bool, drive) -> SimpleNamespace:
     """Weights, database, hosts and a warm-up pass of the cell's own
     traffic drawn from another seed, through the window's own driver."""
-    mc = program.model_config(conf, rehearsal)
+    mc = program.model_config(conf, rehearsal, arch)
     program.install_weights(seed)
-    ctx = SimpleNamespace(mix=mix, mc=mc, spans=drivers.Spans(),
+    ctx = SimpleNamespace(mix=mix, mc=mc, arch=arch, spans=drivers.Spans(),
                           limits=conf["rehearsal_limits" if rehearsal
                                       else "limits"],
                           log=program.RequestLog(),
@@ -286,8 +316,8 @@ def verify(ctx, win, mix: dict, seed: int,
     ctx.hosts = None
     gc.collect()
     params = weights.make_params(program.param_shapes(ctx.mc), seed)
-    dims = program.dims_of(ctx.mc)
-    gap, n_tok, raw = check.model_gap(params, dims, calls)
+    dims = ctx.arch.dims(ctx.mc)
+    gap, n_tok, raw = check.model_gap(ctx.arch, params, dims, calls)
     limits = ctx.limits
     out = {
         "failed": {"value": sum(1 for q in win.queries if q["failed"]),
@@ -303,7 +333,8 @@ def verify(ctx, win, mix: dict, seed: int,
                              "limit": int(mix["check"]["min_tokens"]),
                              "ok": n_tok >= int(mix["check"]["min_tokens"])}
     if control:
-        cgap, _, craw = check.model_gap(params, dims, calls, fp8=True)
+        cgap, _, craw = check.model_gap(ctx.arch, params, dims, calls,
+                                        fp8=True)
         out["control_gap_sd"] = {"value": cgap, "limit": limits["gap_sd"],
                                  "ok": cgap is not None
                                  and cgap <= limits["gap_sd"]}

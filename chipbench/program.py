@@ -26,40 +26,24 @@ def import_program(root: str):
         sys.path.insert(0, src)
 
 
-def model_config(conf: dict, rehearsal: bool):
+def model_config(conf: dict, rehearsal: bool, arch):
     """The ``ModelConfig`` a configuration file states: its arch's
     published config with the file's ``overrides`` (``rehearsal``: the
     arch's smoke preset with ``rehearsal_overrides``), checked key by key
-    against the file's published numbers."""
+    against what the file fixes, ``arch.expect(conf)``."""
     from repro.configs import get_config, get_smoke
     if rehearsal:
         mc = get_smoke(conf["arch"]).replace(
             **conf.get("rehearsal_overrides", {}))
         return mc
     mc = get_config(conf["arch"]).replace(**conf.get("overrides", {}))
-    want = {"d_model": conf["hidden_size"], "num_layers":
-            conf["num_hidden_layers"], "num_heads":
-            conf["num_attention_heads"], "num_kv_heads":
-            conf["num_key_value_heads"], "d_ff": conf["intermediate_size"],
-            "vocab_size": conf["vocab_size"], "resolved_head_dim":
-            conf["head_dim"], "tie_embeddings": conf["tie_word_embeddings"],
-            "rope_theta": float(conf["rope_theta"]), "norm_eps":
-            conf["rms_norm_eps"], "qk_norm": True, "family": "dense",
-            "dtype": conf["torch_dtype"], "param_dtype":
-            conf["torch_dtype"], "attention_impl": "xla"}
+    want = arch.expect(conf)
     bad = {k: (getattr(mc, k), v) for k, v in want.items()
            if getattr(mc, k) != v}
     if bad:
         raise ValueError(f"{conf['arch']} as run differs from its "
                          f"configuration file: {bad}")
     return mc
-
-
-def dims_of(mc) -> dict:
-    """The sizes the plain reference needs, read from the config run."""
-    return {"heads": mc.num_heads, "kv_heads": mc.num_kv_heads,
-            "head_dim": mc.resolved_head_dim, "rope_theta": mc.rope_theta,
-            "eps": mc.norm_eps, "vocab": mc.vocab_size}
 
 
 def param_shapes(mc):

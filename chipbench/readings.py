@@ -10,15 +10,7 @@ from __future__ import annotations
 
 from typing import Optional
 
-
-def matmul_params(conf: dict) -> tuple:
-    """(per-layer matmul parameters summed over the layers run, output
-    head parameters) of a configuration file."""
-    d, ff = conf["hidden_size"], conf["intermediate_size"]
-    h, kv, dh = (conf["num_attention_heads"], conf["num_key_value_heads"],
-                 conf["head_dim"])
-    layer = d * (h + 2 * kv) * dh + h * dh * d + 3 * d * ff
-    return conf["num_hidden_layers"] * layer, d * conf["vocab_size"]
+from chipbench import harness
 
 
 def mfu(reading) -> Optional[float]:
@@ -26,11 +18,13 @@ def mfu(reading) -> Optional[float]:
     the window and the chip's bf16 peak, in %.  Every prefilled and
     decoded token passes the layers' matmuls; the head runs once per
     decoded token and once per prefill (the last prompt position).
-    Attention's own FLOPs are left out, so this undercounts."""
+    The matmul parameters are the architecture's count
+    (``matmul_params``); attention's own FLOPs are left out, so this
+    undercounts."""
     if reading.peak is None:
         return None
     win = reading.window
-    layers, head = matmul_params(reading.conf)
+    layers, head = harness.arch_of(reading.conf).matmul_params(reading.conf)
     tokens = win.delta("prefill_tokens") + win.delta("decode_tokens")
     prefills = max(reading.served - win.delta("coalesced_requests"), 0)
     flops = 2.0 * (layers * tokens

@@ -33,7 +33,7 @@ import statistics
 import time
 from typing import Dict, List, Optional, Tuple
 
-from chipbench import readings
+from chipbench import harness
 from chipbench import trace as T
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -290,26 +290,16 @@ def kv_pool_used_share(a: Optional[dict]) -> Optional[float]:
     return 100.0 * a["pool_pages_used"] / a["pool_pages_total"]
 
 
-def decode_step_bytes(a: dict, conf: dict) -> float:
-    """Bytes a decode step must read: every layer and head matmul
-    parameter at 2 bytes, and the live KV tokens' keys and values at 2
-    bytes, whatever implements the step or stores the pool."""
-    layers, head = readings.matmul_params(conf)
-    kv_tokens = a["decode_kv_tokens"] / a["decode_steps"]
-    kv = (kv_tokens * conf["num_hidden_layers"] * 2
-          * conf["num_key_value_heads"] * conf["head_dim"] * 2)
-    return 2.0 * (layers + head) + kv
-
-
 def decode_hbm_share(a: Optional[dict], conf: dict,
                      peak: Optional[dict]) -> Optional[float]:
-    """Bytes a decode step must read over the mean device time of
+    """Bytes a decode step must read (the architecture's count,
+    ``decode_step_bytes``) over the mean device time of
     ``jit_paged_decode_step``, as a share of the chip's HBM bandwidth."""
     if not a or peak is None or not a["decode_steps"] \
             or not a["decode_program_s"]:
         return None
     t = statistics.fmean(a["decode_program_s"])
-    return 100.0 * decode_step_bytes(a, conf) / t \
+    return 100.0 * harness.arch_of(conf).decode_step_bytes(a, conf) / t \
         / float(peak["hbm_bytes_per_s"])
 
 

@@ -4,11 +4,12 @@
 
 They check the yardstick, not the speed: the traffic generator, the
 metric arithmetic, the refusal of an unknown chip or of a CPU run, a
-cell added by files alone, the plain reference against the program's
-cold prefill, prefix-shared chunk prefill and paged decode, the float8
-control turning ``correct`` false under the configuration's limit, and
-a broken timed path turning it false too.  Rehearsals run the real
-harness with ``rehearsal=True``.
+cell and an architecture module added by files alone, a configuration
+file checked against its architecture, the plain reference against the
+program's cold prefill, prefix-shared chunk prefill and paged decode,
+the float8 control turning ``correct`` false under the configuration's
+limit, and a broken timed path turning it false too.  Rehearsals run
+the real harness with ``rehearsal=True``.
 """
 from __future__ import annotations
 
@@ -77,9 +78,10 @@ def _window(queries, seconds=10.0, **delta):
 
 def test_mfu_arithmetic(bench):
     conf = bench.config("qwen3-1.7b")
-    layers, head = readings.matmul_params(conf)
+    arch = bench.arch(conf)
+    layers, head = arch.matmul_params(conf)
     assert layers + head == pytest.approx(1.721e9, rel=1e-3)
-    l8, h8 = readings.matmul_params(bench.config("qwen3-8b-pp2"))
+    l8, h8 = arch.matmul_params(bench.config("qwen3-8b-pp2"))
     assert l8 + h8 == pytest.approx(4.096e9, rel=1e-3)
     r = SimpleNamespace(window=_window([], prefill_tokens=1000,
                                        decode_tokens=9000,
@@ -139,8 +141,9 @@ def test_trace_reduction_on_a_chip_trace():
 
 # ---------------------------------------------------- cells from files
 def fixture_root(tmp_path):
-    """A checkout with one more configuration, mix, metric and cell, all
-    added as files and entries only."""
+    """A checkout with one more configuration, mix, metric and cell, and
+    one more architecture module (a copy of qwen3's under another
+    ``model_type``), all added as files and entries only."""
     root = tmp_path / "checkout"
     shutil.copytree(os.path.join(ROOT, "chipbench"), root / "chipbench",
                     ignore=shutil.ignore_patterns("out", "__pycache__",
@@ -159,6 +162,8 @@ def fixture_root(tmp_path):
     mix["check"] = {"queries": 4, "min_tokens": 100}
     (root / "chipbench" / "traffic" / "fixture-mix.json").write_text(
         json.dumps(mix))
+    shutil.copy(os.path.join(ROOT, "chipbench", "arch", "qwen3.py"),
+                root / "chipbench" / "arch" / f"{FIXTURE_ARCH}.py")
     (root / "chipbench" / "metrics" / "decode_per_query.batch.py"
      ).write_text("def read(r):\n    n = len(r.window.queries)\n"
                   "    return r.window.delta('decode_tokens') / n\n")
@@ -179,6 +184,9 @@ def fixture_root(tmp_path):
                               "workloads": [cell]})
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     return str(root)
+
+
+FIXTURE_ARCH = "fixture_arch"
 
 
 @pytest.fixture(scope="module")
@@ -215,19 +223,63 @@ def test_rehearsal_of_an_added_batch_cell(fixture_bench):
     assert set(res["metrics"]) == {"setup_s", "queries_per_s"}
 
 
+# ------------------------------------- architectures found by model_type
+def test_a_configuration_with_no_architecture_module_is_refused(bench):
+    conf = dict(bench.config("qwen3-1.7b"), model_type="no_such_arch")
+    path = os.path.join(ROOT, "chipbench", "arch", "no_such_arch.py")
+    with pytest.raises(harness.SetupError, match=path):
+        bench.arch(conf)
+
+
+def test_an_architecture_module_added_under_a_root_is_found(fixture_bench,
+                                                             bench):
+    conf = dict(bench.config("qwen3-1.7b"), model_type=FIXTURE_ARCH)
+    arch = fixture_bench.arch(conf)
+    assert arch.__file__ == os.path.join(fixture_bench.root, "chipbench",
+                                         "arch", f"{FIXTURE_ARCH}.py")
+    assert fixture_bench.arch(conf) is arch
+    qwen3 = bench.arch(bench.config("qwen3-1.7b"))
+    assert arch is not qwen3 and arch.expect(conf) == qwen3.expect(conf)
+    assert program.model_config(conf, False, arch).d_model == 2048
+    with pytest.raises(harness.SetupError):
+        bench.arch(conf)                # not in this checkout
+
+
+DISAGREE = {"hidden_size": lambda v: v // 2,
+            "intermediate_size": lambda v: v + 128,
+            "tie_word_embeddings": lambda v: not v,
+            "rms_norm_eps": lambda v: v * 10}
+
+
+@pytest.mark.parametrize("key", sorted(DISAGREE))
+def test_a_configuration_that_disagrees_with_its_architecture_is_refused(
+        bench, key):
+    for name in ("qwen3-1.7b", "qwen3-8b-pp2"):
+        conf = bench.config(name)
+        arch = bench.arch(conf)
+        program.model_config(conf, False, arch)        # as committed: agrees
+        with pytest.raises(ValueError, match="differs from its "
+                                             "configuration file"):
+            program.model_config(dict(conf, **{key: DISAGREE[key](
+                conf[key])}), False, arch)
+
+
 # ------------------------------------------ reference against the program
 @pytest.mark.parametrize("arch", ["qwen3-1.7b", "qwen3-8b"])
-def test_reference_matches_prefill_chunk_prefill_and_paged_decode(arch):
+def test_reference_matches_prefill_chunk_prefill_and_paged_decode(arch,
+                                                                   bench):
     import jax
     import jax.numpy as jnp
-    from chipbench import reference, weights
+    from chipbench import weights
     from repro.configs import get_smoke
     from repro.engine.kvcache import PagedKVCache
     from repro.engine.models import build_model
+    reference = bench.arch(bench.config({"qwen3-1.7b": "qwen3-1.7b",
+                                         "qwen3-8b": "qwen3-8b-pp2"}[arch]))
     mc = get_smoke(arch)
     model = build_model(mc)
     params = weights.make_params(program.param_shapes(mc), 2**40 + 3)
-    dims = program.dims_of(mc)
+    dims = reference.dims(mc)
     rng = np.random.default_rng(0)
     toks = rng.integers(8, mc.vocab_size, 40).tolist()
     ref = reference.logits_at(params, dims, [(toks, 23)])[0]

@@ -19,7 +19,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
 sys.path.insert(0, ROOT)
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
-from chipbench import harness, program, readings, spans  # noqa: E402
+from chipbench import harness, program, spans  # noqa: E402
 from chipbench import trace as T  # noqa: E402
 
 DATA = os.path.join(ROOT, "chipbench", "tests", "data")
@@ -166,10 +166,11 @@ def test_each_new_reader_on_a_synthetic_reading(bench_with_analysis):
     assert b.reader("kv_pool_used_share.batch")(r) == pytest.approx(50.0)
     assert b.reader("idle_engine_host_share.batch")(r) == \
         pytest.approx(20.0)
-    layers, head = readings.matmul_params(r.conf)
+    arch = b.arch(r.conf)
+    layers, head = arch.matmul_params(r.conf)
     # mean live KV 300 tokens x 28 layers x (K, V) x 8 heads x 128 x 2 B
     want = 2 * (layers + head) + 300 * 28 * 2 * 8 * 128 * 2
-    assert spans.decode_step_bytes(a, r.conf) == want
+    assert arch.decode_step_bytes(a, r.conf) == want
     share = b.reader("decode_hbm_share.batch")(r)
     assert share == pytest.approx(100 * want / 0.01675 / 819e9)
     assert 0 < share <= 100

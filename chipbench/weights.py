@@ -4,8 +4,9 @@ The benchmark owns the weights: ``make_params`` draws every leaf of the
 served model's parameter tree from the run's seed, in the dtype the
 model serves it in, and the engines are handed that tree (see
 ``program.install_weights``).  The plain reference reads the same draws
-through ``canonical_layer`` / ``canonical_head``, so it takes nothing the
-program made.
+through its architecture module's view of the tree (``arch/<model_type>.py``
+``canonical_layer`` / ``canonical_head``), so it takes nothing the program
+made.
 
 Scales: matrices N(0, 1/fan_in), the embedding N(0, 0.02^2), and every
 RMSNorm gain 1 + N(0, 0.1^2).  The program stores a norm gain as its
@@ -68,43 +69,3 @@ def make_params(shape_tree, seed: int):
     """Every leaf of ``shape_tree`` drawn from ``seed``, on the default
     device, in one compiled program."""
     return _draw(layout_of(shape_tree), seed_key(seed))
-
-
-# ----------------------------------------------------- the reference's view
-def canonical_layer(params, i: int) -> dict:
-    """Layer ``i``'s weights as the published architecture names them,
-    in float32: projection matrices (in, out) and RMSNorm gains."""
-    b = params["blocks"]
-
-    def f32(a):
-        return jnp.asarray(a[i], jnp.float32)
-
-    return {
-        "input_norm": 1.0 + f32(b["ln1"]),
-        "post_attn_norm": 1.0 + f32(b["ln2"]),
-        "q_proj": f32(b["attn"]["wq"]), "k_proj": f32(b["attn"]["wk"]),
-        "v_proj": f32(b["attn"]["wv"]), "o_proj": f32(b["attn"]["wo"]),
-        "q_norm": 1.0 + f32(b["attn"]["q_norm"]),
-        "k_norm": 1.0 + f32(b["attn"]["k_norm"]),
-        "gate_proj": f32(b["ffn"]["w_gate"]),
-        "up_proj": f32(b["ffn"]["w_up"]),
-        "down_proj": f32(b["ffn"]["w_down"]),
-    }
-
-
-def canonical_head(params) -> dict:
-    """The embedding table (rows = token ids, padded past the published
-    vocabulary), the final norm gain in float32, and the output head as
-    ``head_rows``: the (vocab, d) matrix whose row v scores token v —
-    the embedding itself when the model ties them.  Tables stay in their
-    stored dtype; the reference upcasts the rows it uses."""
-    head = params["embed"] if "lm_head" not in params else \
-        params["lm_head"].T
-    return {"embed": params["embed"],
-            "final_norm": 1.0 + jnp.asarray(params["final_norm"],
-                                            jnp.float32),
-            "head_rows": head}
-
-
-def num_layers(params) -> int:
-    return int(params["blocks"]["ln1"].shape[0])
