@@ -33,7 +33,7 @@ class MoEConfig:
     num_shared_experts: int = 0
     first_dense_layers: int = 0          # leading layers that use a dense FFN
     d_ff_dense: int = 0                  # width of those dense FFNs
-    capacity_factor: float = 1.25
+    norm_topk_prob: bool = True          # renormalise the top-K gate weights
     router_aux_weight: float = 0.01
 
 

@@ -18,7 +18,8 @@ CONFIG = ModelConfig(
     head_dim=128,
     rope_theta=1000000.0,
     swa_window=4096,
-    moe=MoEConfig(num_experts=8, top_k=2, d_ff_expert=16384),
+    moe=MoEConfig(num_experts=8, top_k=2, d_ff_expert=16384,
+                  norm_topk_prob=True),
 )
 
 
@@ -26,5 +27,6 @@ def smoke_config() -> ModelConfig:
     return CONFIG.replace(
         num_layers=3, d_model=64, num_heads=4, num_kv_heads=2, head_dim=16,
         d_ff=128, vocab_size=256, swa_window=16,
-        moe=MoEConfig(num_experts=4, top_k=2, d_ff_expert=128),
+        moe=MoEConfig(num_experts=4, top_k=2, d_ff_expert=128,
+                      norm_topk_prob=True),
     )

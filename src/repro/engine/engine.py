@@ -88,6 +88,16 @@ def _batched_sample(logits, keys, temps, *, vocab_size: int):
     return tokens, jnp.where(stoch[:, None], new_keys, keys)
 
 
+@functools.partial(jax.jit, static_argnames=("vocab_size",))
+def _sample_counted(logits, keys, temps, read, *, vocab_size: int):
+    """``_batched_sample`` for a MoE step: the step's experts read (int32
+    scalar) rides after the (B,) ids, so that it reaches the host in the
+    same transfer."""
+    tokens, keys = _batched_sample(logits, keys, temps,
+                                   vocab_size=vocab_size)
+    return jnp.append(tokens, read), keys
+
+
 @functools.partial(jax.jit, static_argnums=0)
 def _init_params(cfg: ModelConfig, key):
     """Seeded random weights in one compiled program (cached per config):
@@ -106,6 +116,9 @@ class EngineStats:
     pool_pages_used: int = 0             # pages not free, summed over steps
     pool_pages_total: int = 0            # num_pages, summed over steps
     admit_deferrals: int = 0             # admissions deferred for pages
+    moe_token_slots: int = 0             # token-expert slots computed
+    moe_experts_read: int = 0            # experts with a slot, summed over
+                                         # MoE layer calls
     coalesced_requests: int = 0
     prefix_hits: int = 0
     admission_waves: int = 0             # scheduler passes that admitted >=1
@@ -271,22 +284,30 @@ class InferenceEngine:
         self.num_pages = num_pages or max(
             64, 2 * max_batch * -(-max_seq_len // page_size))
         self.kv: Optional[PagedKVCache] = None   # guarded-by: self._cv | engine-loop
+        # a MoE model's steps also return the experts they read, which
+        # come back with the sampled ids (EngineStats.moe_*)
+        m = cfg.moe
+        self._moe_slots = (m.top_k * (cfg.num_layers - m.first_dense_layers)
+                           if m else 0)      # slots a token takes
+        count = {"count_experts": True} if m else {}
         # jitted steps (cached per input/cache shape signature), named so
         # the device trace's program line reads jit_<name>
         model = self.model
 
         def decode_step(p, tok, cache):
-            return model.decode_step(p, tok, cache)
+            return model.decode_step(p, tok, cache, **count)
 
         def prefill(p, toks):
-            return model.prefill(p, toks)
+            return model.prefill(p, toks, **count)
 
         def chunk_prefill(p, toks, cache, n):
-            return model.prefill_with_cache(p, toks, cache, valid_len=n)
+            return model.prefill_with_cache(p, toks, cache, valid_len=n,
+                                            **count)
 
         def paged_decode_step(p, tok, kp, vp, pt, ln):
             return model.paged_decode_step(p, tok, kp, vp, pt, ln,
-                                           variant=self.kernel_variant)
+                                           variant=self.kernel_variant,
+                                           **count)
 
         # jit-ok: cache/toks shapes ARE the bucketing keys (view pad, _round_t)
         self._decode_jit = jax.jit(decode_step)
@@ -882,21 +903,41 @@ class InferenceEngine:
         with obs.span("engine.prefill", rid=req.rid, prompt_tokens=S,
                       shared_tokens=shared) as sp:
             self._queue_done(req, sp.t0)
-            logits = self._prefill_into(slot, donor, shared, shareable)
+            logits, read = self._prefill_into(slot, donor, shared, shareable)
+            if self._moe_slots:
+                slots = (S - shared) * self._moe_slots
+                self.stats.moe_token_slots += slots
+                sp.set(slots=slots)
+            # the experts read come back with the first sampled id (a
+            # prompt that samples nothing leaves them uncounted)
             if req.max_new > 0:
-                self._emit_token(slot, logits[0:1])
+                self._count_read(sp, self._emit_token(slot, logits[0:1],
+                                                      read))
         return slot
+
+    def _split_count(self, out: tuple):
+        """(a jitted step's outputs, the experts it read or None)."""
+        return (out[:-1], out[-1]) if self._moe_slots else (out, None)
+
+    def _count_read(self, sp, read: Optional[int]) -> None:
+        """Count a step's experts read, already on the host (None for a
+        dense model), onto ``sp`` and the stats."""
+        if read is not None:
+            self.stats.moe_experts_read += read
+            sp.set(experts_read=read)
 
     def _prefill_into(self, slot: _Slot, donor: Optional[int], shared: int,
                       shareable: bool):
         """Prefill ``slot``'s prompt into its cache (the donor's first
-        ``shared`` tokens aliased); the last position's logits."""
+        ``shared`` tokens aliased); the last position's logits and, for a
+        MoE model, the experts read (a device scalar, else None)."""
         req = slot.req
         S = len(req.prompt)
+        read = None
         if self._paged_layout:
             kv = self.kv
             if donor is not None:
-                logits = self._prefill_shared(slot, donor, shared)
+                logits, read = self._prefill_shared(slot, donor, shared)
                 self.stats.prefix_hits += 1
                 self.stats.prefill_tokens += S - shared
                 self.stats.prefill_tokens_saved += shared
@@ -907,11 +948,12 @@ class InferenceEngine:
                 # timing-dependent under streaming arrivals, so giving
                 # the cold path its own per-length compiled shape would
                 # re-trace run to run
-                logits = self._prefill_cold(slot)
+                logits, read = self._prefill_cold(slot)
                 self.stats.prefill_tokens += S
             else:
                 tokens = jnp.asarray([req.prompt], jnp.int32)
-                logits, cache = self._prefill(tokens, req.extra)
+                (logits, cache), read = self._split_count(
+                    self._prefill(tokens, req.extra))
                 S_kv = S
                 if req.extra.get("patch_embeds") is not None:
                     S_kv += req.extra["patch_embeds"].shape[-2]
@@ -926,13 +968,14 @@ class InferenceEngine:
             self.stats.tokens_reused = kv.tokens_reused
         else:
             tokens = jnp.asarray([req.prompt], jnp.int32)
-            logits, cache = self._prefill(tokens, req.extra)
+            (logits, cache), read = self._split_count(
+                self._prefill(tokens, req.extra))
             t_cur = S
             slot.row = self.model.extend_cache(cache,
                                                self.max_seq_len - t_cur)
             slot.length = S
             self.stats.prefill_tokens += S
-        return logits
+        return logits, read
 
     def _prefill_cold(self, slot: _Slot):
         """Prefill a donor-less prompt via the bucketed chunk step over
@@ -948,11 +991,11 @@ class InferenceEngine:
         v_rows = jnp.zeros((1, layers, T1, heads, dh), jnp.float32)
         cache = self.model.paged_cache_view(k_rows, v_rows, [0])
         toks = jnp.asarray([list(req.prompt) + [0] * (pad - S)], jnp.int32)
-        logits, cache = self._chunk_prefill_jit(
-            self.params, toks, cache, jnp.asarray([S], jnp.int32))
+        (logits, cache), read = self._split_count(self._chunk_prefill_jit(
+            self.params, toks, cache, jnp.asarray([S], jnp.int32)))
         k_row, v_row = self._kv_rows(cache, 0, S)           # (L, S, H, D)
         slot.seq_id = kv.add_sequence(k_row, v_row)
-        return logits
+        return logits, read
 
     def _prefill_shared(self, slot: _Slot, donor: int, shared: int):
         """Admit via page aliasing: reuse the donor's first ``shared``
@@ -980,11 +1023,11 @@ class InferenceEngine:
         cache = self.model.paged_cache_view(k_rows, v_rows, [shared])
         suffix = jnp.asarray(
             [list(req.prompt[shared:]) + [0] * (pad - n_suf)], jnp.int32)
-        logits, cache = self._chunk_prefill_jit(
-            self.params, suffix, cache, jnp.asarray([n_suf], jnp.int32))
+        (logits, cache), read = self._split_count(self._chunk_prefill_jit(
+            self.params, suffix, cache, jnp.asarray([n_suf], jnp.int32)))
         k_row, v_row = self._kv_rows(cache, 0, S)           # (L, S, H, D)
         kv.extend_sequence(seq, k_row[:, shared:], v_row[:, shared:])
-        return logits
+        return logits, read
 
     # ---------------------------------------------------------------- decode
     def _round_t(self, n: int) -> int:
@@ -1059,11 +1102,14 @@ class InferenceEngine:
         dispatch (span ``engine.decode``), then sampling and the sync."""
         b_real = len(self._active)
         with obs.span("engine.decode", rows=b_real) as sp:
-            logits = (self._decode_paged(sp) if self._use_paged
-                      else self._decode_dense())
+            logits, read = (self._decode_paged(sp) if self._use_paged
+                            else self._decode_dense())
+            if self._moe_slots:
+                self.stats.moe_token_slots += b_real * self._moe_slots
+                sp.set(slots=b_real * self._moe_slots)
         self.stats.decode_steps += 1
         self.stats.decode_tokens += b_real
-        self._advance(logits)
+        self._advance(logits, read)
 
     def _decode_dense(self):
         if self._dirty:
@@ -1076,8 +1122,8 @@ class InferenceEngine:
         tokens[:b_real] = [s.last_token for s in slots]
         prev_lengths = [s.length for s in slots]
         self.stats.h2d_bytes += tokens.nbytes
-        logits, self._view = self._decode_jit(
-            self.params, jnp.asarray(tokens), self._view)
+        (logits, self._view), read = self._split_count(self._decode_jit(
+            self.params, jnp.asarray(tokens), self._view))
         if self._paged_layout:
             taps_ix = np.zeros((self._view_pad,), np.int32)
             taps_ix[:b_real] = prev_lengths      # identity slots (no wrap)
@@ -1090,13 +1136,14 @@ class InferenceEngine:
             self.kv.append_tokens([s.seq_id for s in slots], k_taps, v_taps)
         for s in slots:
             s.length += 1
-        return logits
+        return logits, read
 
     def _decode_paged(self, sp):
         """One decode step straight over the device-resident page pool:
         upload O(batch) metadata (tokens, page tables, lengths), run the
         paged step (in-pool KV scatter + paged attention), and return its
-        logits; O(batch) sampled ids come back in ``_advance``.  No dense
+        logits (and the experts read, for a MoE model); O(batch) sampled
+        ids come back in ``_advance``.  No dense
         view exists, so composition changes are free — no
         ``_rebuild_view``, no KV tap sync.  Counts the live KV tokens the
         step reads and the pool's pages in use onto ``sp`` and the
@@ -1128,17 +1175,19 @@ class InferenceEngine:
             lens[i] = s.length
             tokens[i] = s.last_token
         self.stats.h2d_bytes += pt.nbytes + lens.nbytes + tokens.nbytes
-        logits, new_k, new_v = self._paged_step_jit(
+        (logits, new_k, new_v), read = self._split_count(self._paged_step_jit(
             self.params, jnp.asarray(tokens), kv.k, kv.v,
-            jnp.asarray(pt), jnp.asarray(lens))
+            jnp.asarray(pt), jnp.asarray(lens)))
         kv.adopt_pages(new_k, new_v)
         kv.commit_appends([s.seq_id for s in slots])
         for s in slots:
             s.length += 1
-        return logits
+        return logits, read
 
-    def _emit_token(self, slot: _Slot, logits) -> None:
-        """Sample one token for ``slot`` from (1, Vpad) logits."""
+    def _emit_token(self, slot: _Slot, logits, read=None) -> Optional[int]:
+        """Sample one token for ``slot`` from (1, Vpad) logits; the
+        prefill's experts read (``read``, a device scalar or None) comes
+        back in the same transfer and is returned as a host int."""
         if slot.req.temperature == 0.0:
             nxt = sample(logits, self._zero_key, temperature=0.0,
                          vocab_size=self.cfg.vocab_size)
@@ -1146,17 +1195,23 @@ class InferenceEngine:
             slot.rng, sub = jax.random.split(slot.rng)
             nxt = sample(logits, sub, temperature=slot.req.temperature,
                          vocab_size=self.cfg.vocab_size)
-        tok = int(nxt[0])
+        if read is not None:
+            nxt = jnp.append(nxt, read)
+        # not-a-transfer: the admission-time sync of the prefill's one id
+        got = np.asarray(nxt)            # (and its experts read)
+        tok = int(got[0])
         slot.generated.append(tok)
         slot.last_token = tok
         slot.remaining -= 1
+        return None if read is None else int(got[1])
 
-    def _advance(self, logits) -> None:
+    def _advance(self, logits, read=None) -> None:
         """Advance every active slot from one decode step's logits.
 
         The whole (B, Vpad) batch is sampled in a single device call and
-        synced once (one O(batch)-ints D2H per step) — no per-slot
-        logits slicing or per-sequence ``int()`` syncs."""
+        synced once (one O(batch)-ints D2H per step, the step's experts
+        read with them) — no per-slot logits slicing or per-sequence
+        ``int()`` syncs."""
         slots = list(self._active)
         b = len(slots)
         b_pad = logits.shape[0]          # sample the PADDED batch so the
@@ -1169,11 +1224,17 @@ class InferenceEngine:
                              + [zero] * (b_pad - b))
         else:                            # greedy rows never read their key
             keys = jnp.zeros((b_pad, 2), jnp.uint32)
-        toks_dev, new_keys = _batched_sample(
-            logits, keys, jnp.asarray(temps_pad),
-            vocab_size=self.cfg.vocab_size)
-        with obs.span("engine.sync"):
+        if read is None:
+            toks_dev, new_keys = _batched_sample(
+                logits, keys, jnp.asarray(temps_pad),
+                vocab_size=self.cfg.vocab_size)
+        else:
+            toks_dev, new_keys = _sample_counted(
+                logits, keys, jnp.asarray(temps_pad), read,
+                vocab_size=self.cfg.vocab_size)
+        with obs.span("engine.sync") as sp:
             toks = np.asarray(toks_dev)  # one O(batch)-ints sync per step
+            self._count_read(sp, None if read is None else int(toks[-1]))
         self.stats.d2h_bytes += toks.nbytes
         finished = []
         for i, s in enumerate(slots):

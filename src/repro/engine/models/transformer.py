@@ -35,6 +35,10 @@ class TransformerLM:
         self.n_lead = m.first_dense_layers if m else 0     # unscanned lead layers
         self.n_scan = cfg.num_layers - self.n_lead
 
+    def _scan_index(self) -> jax.Array:
+        """0 .. n_scan-1: each scanned layer's index in the stacked blocks."""
+        return jnp.arange(self.n_scan, dtype=jnp.int32)
+
     # ------------------------------------------------------------------ init
     def _block_init(self, rng, lead: bool):
         cfg = self.cfg
@@ -73,7 +77,8 @@ class TransformerLM:
         return params
 
     # ----------------------------------------------------------------- block
-    def _block(self, p, x, positions, aux, *, impl=None):
+    def _block(self, p, x, positions, aux, *, impl=None, experts=None,
+               layer=None):
         cfg = self.cfg
         impl = impl or cfg.attention_impl
         if cfg.family == "dense":
@@ -91,12 +96,31 @@ class TransformerLM:
                         causal=True, window=cfg.swa_window, impl=impl)
         x = x + L.attn_out(p["attn"], o)
         h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
+        y, a, _ = self._ffn(p, h, experts=experts, layer=layer)
+        return x + y, aux + a
+
+    def _ffn(self, p, h, valid=None, experts=None, layer=None):
+        """A block's feed-forward on ``h``: (output, aux loss, experts
+        read).  ``valid`` (B, S) marks real tokens; a MoE block routes
+        padded ones to no expert.  ``experts``: the routed experts'
+        weights of every scanned layer (``_split_experts``), of which the
+        block is scanned layer ``layer``."""
         if "moe" in p:
-            y, a = M.moe_ffn(h, p["moe"], cfg.moe)
-            aux = aux + a
-        else:
-            y = L.ffn_apply(p["ffn"], h)
-        return x + y, aux
+            mp = p["moe"] if experts is None else {**p["moe"], **experts}
+            return M.moe_ffn(h, mp, self.cfg.moe, valid, layer)
+        return L.ffn_apply(p["ffn"], h), jnp.float32(0.0), jnp.int32(0)
+
+    def _split_experts(self, blocks):
+        """(the scanned blocks without their routed experts' weights,
+        those weights stacked over the layers, or None).  The layer scans
+        carry the experts whole and hand each layer its index instead of
+        its slice: a scan's slice of the (E, D, F) weights would copy
+        every expert of the layer, read or not, on every call."""
+        if "moe" not in blocks:
+            return blocks, None
+        moe = dict(blocks["moe"])
+        experts = {k: moe.pop(k) for k in ("w_gate", "w_up", "w_down")}
+        return dict(blocks, moe=moe), experts
 
     # --------------------------------------------------------------- forward
     def forward(self, params: Params, tokens: jax.Array,
@@ -113,15 +137,18 @@ class TransformerLM:
 
         for p in params.get("lead_blocks", []):
             x, aux = self._block(p, x, positions, aux)
+        blocks, experts = self._split_experts(params["blocks"])
 
-        def body(carry, p):
+        def body(carry, xs):
             x, aux = carry
-            x, aux = self._block(p, x, positions, aux)
+            p, l = xs
+            x, aux = self._block(p, x, positions, aux, experts=experts,
+                                 layer=l)
             return (x, aux), None
 
         if remat:
             body = jax.checkpoint(body, policy=jax.checkpoint_policies.nothing_saveable)
-        (x, aux), _ = lax.scan(body, (x, aux), params["blocks"])
+        (x, aux), _ = lax.scan(body, (x, aux), (blocks, self._scan_index()))
 
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -273,9 +300,10 @@ class TransformerLM:
     # --------------------------------------------------------------- prefill
     def prefill(self, params: Params, tokens: jax.Array,
                 prefix_embeds: Optional[jax.Array] = None,
-                impl: Optional[str] = None
-                ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        """Run the full prompt; return (last-position logits, filled cache)."""
+                impl: Optional[str] = None, count_experts: bool = False):
+        """Run the full prompt; return (last-position logits, filled
+        cache), and with ``count_experts`` the experts read, summed over
+        the MoE layers (int32)."""
         cfg = self.cfg
         x = params["embed"][tokens]
         if prefix_embeds is not None:
@@ -284,7 +312,7 @@ class TransformerLM:
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32), (B, S))
         T = self.cache_capacity(S)
 
-        def run_block(p, x):
+        def run_block(p, x, experts=None, layer=None):
             if cfg.family == "dense":      # sequence-parallel residual (SP)
                 x = L.constrain_hidden(x)
             h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -298,23 +326,27 @@ class TransformerLM:
                             window=cfg.swa_window, impl=impl or cfg.attention_impl)
             x = x + L.attn_out(p["attn"], o)
             h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-            if "moe" in p:
-                y, _ = M.moe_ffn(h, p["moe"], cfg.moe)
-            else:
-                y = L.ffn_apply(p["ffn"], h)
+            y, _, read = self._ffn(p, h, experts=experts, layer=layer)
             # keep only the cache window (T divides S for all assigned shapes)
-            return x + y, (k[:, S - T:], v[:, S - T:])
+            return x + y, (k[:, S - T:], v[:, S - T:]), read
 
         lead_kv = []
+        read = jnp.int32(0)
         for p in params.get("lead_blocks", []):
-            x, kv = run_block(p, x)
+            x, kv, r = run_block(p, x)
             lead_kv.append(kv)
+            read = read + r
 
-        def body(x, p):
-            x, kv = run_block(p, x)
-            return x, kv
+        blocks, experts = self._split_experts(params["blocks"])
 
-        x, (ks, vs) = lax.scan(body, x, params["blocks"])   # ks: (L,B,T,Hkv,Dh)
+        def body(carry, xs):
+            x, read = carry
+            p, l = xs
+            x, kv, r = run_block(p, x, experts, l)
+            return (x, read + r), kv
+
+        (x, read), (ks, vs) = lax.scan(body, (x, read),
+                                       (blocks, self._scan_index()))
 
         cache = {
             "k": ks, "v": vs,
@@ -325,14 +357,14 @@ class TransformerLM:
             cache["lead_v"] = jnp.stack([kv[1] for kv in lead_kv])
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        return x[:, -1] @ head, cache
+        return _counted((x[:, -1] @ head, cache), read, count_experts)
 
     # ----------------------------------------------------- chunked prefill
     def prefill_with_cache(self, params: Params, tokens: jax.Array,
                            cache: Dict[str, jax.Array],
                            impl: Optional[str] = None,
-                           valid_len: Optional[jax.Array] = None
-                           ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+                           valid_len: Optional[jax.Array] = None,
+                           count_experts: bool = False):
         """Prefill ``tokens`` (B, S_suf) as a continuation of ``cache``.
 
         The chunk's queries attend to the cached KV (a reused prefix, in
@@ -347,7 +379,9 @@ class TransformerLM:
         timing-dependent prefix-share points reuse one compiled step).
         Causal attention keeps pad rows out of every real row's result;
         logits are read at ``valid_len - 1`` and the cache length
-        advances by ``valid_len``, so padding is bitwise-invisible.
+        advances by ``valid_len``, so padding is bitwise-invisible; a MoE
+        block routes pad rows to no expert.  ``count_experts`` appends
+        the experts read, as in :meth:`prefill`.
         """
         cfg = self.cfg
         assert not cfg.swa_window, "chunked prefill needs full attention"
@@ -359,8 +393,10 @@ class TransformerLM:
         arange_t = jnp.arange(T, dtype=jnp.int32)[None, :]
         kv_pos = jnp.where(arange_t < (pos0 + Ssuf)[:, None], arange_t, -1)
         batch_ix = jnp.arange(B, dtype=jnp.int32)[:, None]
+        tok_valid = None if valid_len is None else (
+            jnp.arange(Ssuf, dtype=jnp.int32)[None, :] < valid_len[:, None])
 
-        def run_block(p, x, k_cache, v_cache):
+        def run_block(p, x, k_cache, v_cache, experts=None, layer=None):
             if cfg.family == "dense":      # sequence-parallel residual (SP)
                 x = L.constrain_hidden(x)
             h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
@@ -376,30 +412,33 @@ class TransformerLM:
                             impl=impl or cfg.attention_impl)
             x = x + L.attn_out(p["attn"], o)
             h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-            if "moe" in p:
-                y, _ = M.moe_ffn(h, p["moe"], cfg.moe)
-            else:
-                y = L.ffn_apply(p["ffn"], h)
-            return x + y, k_cache, v_cache
+            y, _, read = self._ffn(p, h, tok_valid, experts, layer)
+            return x + y, k_cache, v_cache, read
 
         new_cache = dict(cache)
+        read = jnp.int32(0)
         if self.n_lead:
             lk, lv = [], []
             for i, p in enumerate(params["lead_blocks"]):
-                x, k_c, v_c = run_block(p, x, cache["lead_k"][i],
-                                        cache["lead_v"][i])
+                x, k_c, v_c, r = run_block(p, x, cache["lead_k"][i],
+                                           cache["lead_v"][i])
                 lk.append(k_c)
                 lv.append(v_c)
+                read = read + r
             new_cache["lead_k"] = jnp.stack(lk)
             new_cache["lead_v"] = jnp.stack(lv)
 
-        def body(x, xs):
-            p, k_c, v_c = xs
-            x, k_c, v_c = run_block(p, x, k_c, v_c)
-            return x, (k_c, v_c)
+        blocks, experts = self._split_experts(params["blocks"])
 
-        x, (ks, vs) = lax.scan(body, x,
-                               (params["blocks"], cache["k"], cache["v"]))
+        def body(carry, xs):
+            x, read = carry
+            p, k_c, v_c, l = xs
+            x, k_c, v_c, r = run_block(p, x, k_c, v_c, experts, l)
+            return (x, read + r), (k_c, v_c)
+
+        (x, read), (ks, vs) = lax.scan(
+            body, (x, read),
+            (blocks, cache["k"], cache["v"], self._scan_index()))
         new_cache["k"], new_cache["v"] = ks, vs
         new_cache["length"] = pos0 + (Ssuf if valid_len is None
                                       else valid_len)
@@ -407,17 +446,20 @@ class TransformerLM:
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
         if valid_len is None:
-            return x[:, -1] @ head, new_cache
-        last = jnp.take_along_axis(
-            x, (valid_len - 1).astype(jnp.int32)[:, None, None], axis=1)[:, 0]
-        return last @ head, new_cache
+            last = x[:, -1]
+        else:
+            last = jnp.take_along_axis(
+                x, (valid_len - 1).astype(jnp.int32)[:, None, None],
+                axis=1)[:, 0]
+        return _counted((last @ head, new_cache), read, count_experts)
 
     # ------------------------------------------------------------ decode step
     def decode_step(self, params: Params, token: jax.Array,
                     cache: Dict[str, jax.Array],
-                    impl: Optional[str] = None
-                    ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-        """token: (B,) int32. One autoregressive step; updates cache in place."""
+                    impl: Optional[str] = None, count_experts: bool = False):
+        """token: (B,) int32. One autoregressive step; updates cache in
+        place.  ``count_experts`` appends the experts read, as in
+        :meth:`prefill`."""
         cfg = self.cfg
         B = token.shape[0]
         pos = cache["length"]                                  # (B,)
@@ -427,7 +469,7 @@ class TransformerLM:
         kv_pos = self._kv_slot_positions(pos, T)               # (B,T)
         batch_ix = jnp.arange(B, dtype=jnp.int32)
 
-        def step_block(p, x, k_cache, v_cache):
+        def step_block(p, x, k_cache, v_cache, experts=None, layer=None):
             h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
             q, k, v = L.attn_qkv(p["attn"], h, num_heads=cfg.num_heads,
                                  num_kv_heads=cfg.num_kv_heads,
@@ -443,43 +485,47 @@ class TransformerLM:
                             impl=impl or cfg.attention_impl)
             x = x + L.attn_out(p["attn"], o)
             h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-            if "moe" in p:
-                y, _ = M.moe_ffn(h, p["moe"], cfg.moe)
-            else:
-                y = L.ffn_apply(p["ffn"], h)
-            return x + y, k_cache, v_cache
+            y, _, read = self._ffn(p, h, experts=experts, layer=layer)
+            return x + y, k_cache, v_cache, read
 
         new_cache = dict(cache)
+        read = jnp.int32(0)
         if self.n_lead:
             lk, lv = [], []
             for i, p in enumerate(params["lead_blocks"]):
-                x, k_c, v_c = step_block(p, x, cache["lead_k"][i],
-                                         cache["lead_v"][i])
+                x, k_c, v_c, r = step_block(p, x, cache["lead_k"][i],
+                                            cache["lead_v"][i])
                 lk.append(k_c)
                 lv.append(v_c)
+                read = read + r
             new_cache["lead_k"] = jnp.stack(lk)
             new_cache["lead_v"] = jnp.stack(lv)
 
-        def body(x, xs):
-            p, k_c, v_c = xs
-            x, k_c, v_c = step_block(p, x, k_c, v_c)
-            return x, (k_c, v_c)
+        blocks, experts = self._split_experts(params["blocks"])
 
-        x, (ks, vs) = lax.scan(body, x, (params["blocks"], cache["k"], cache["v"]))
+        def body(carry, xs):
+            x, read = carry
+            p, k_c, v_c, l = xs
+            x, k_c, v_c, r = step_block(p, x, k_c, v_c, experts, l)
+            return (x, read + r), (k_c, v_c)
+
+        (x, read), (ks, vs) = lax.scan(
+            body, (x, read),
+            (blocks, cache["k"], cache["v"], self._scan_index()))
         new_cache["k"], new_cache["v"] = ks, vs
         new_cache["length"] = pos + 1
 
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        return (x[:, -1] @ head), new_cache
+        return _counted((x[:, -1] @ head, new_cache), read, count_experts)
 
     # ----------------------------------------------------- paged decode step
     def paged_decode_step(self, params: Params, token: jax.Array,
                           k_pages: jax.Array, v_pages: jax.Array,
                           page_table: jax.Array, lengths: jax.Array,
                           impl: Optional[str] = None,
-                          variant: Optional[str] = None
-                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
+                          variant: Optional[str] = None,
+                          count_experts: bool = False):
         """One autoregressive step straight over the device-resident page
         pool — no dense KV view exists anywhere.
 
@@ -498,8 +544,10 @@ class TransformerLM:
         nothing and are fully masked.  The whole pool is the layer
         scan's carry, so under donation every layer's write lands in
         the donated buffer: no pool-sized temporary, no copy back.
-        Returns ``(logits (B, Vpad), new_k_pages, new_v_pages)``; the
-        caller adopts the returned pool arrays (donated under jit).
+        Returns ``(logits (B, Vpad), new_k_pages, new_v_pages)``, and
+        with ``count_experts`` the experts read (a MoE block routes padded
+        rows to no expert), as in :meth:`prefill`; the caller adopts the
+        returned pool arrays (donated under jit).
         """
         cfg = self.cfg
         impl = impl or cfg.attention_impl
@@ -526,7 +574,7 @@ class TransformerLM:
                                cfg.num_heads // cfg.num_kv_heads)
             eff_variant = variant or kc["variant"]
 
-        def step_block(p, x, kp, vp, l):
+        def step_block(p, x, kp, vp, l, experts=None):
             # kp/vp: the whole pool; l: this layer's index in it
             h = L.rms_norm(x, p["ln1"], cfg.norm_eps)
             q, k, v = L.attn_qkv(p["attn"], h, num_heads=cfg.num_heads,
@@ -571,28 +619,35 @@ class TransformerLM:
                                     window=cfg.swa_window, impl="xla")
             x = x + L.attn_out(p["attn"], o)
             h = L.rms_norm(x, p["ln2"], cfg.norm_eps)
-            if "moe" in p:
-                y, _ = M.moe_ffn(h, p["moe"], cfg.moe)
-            else:
-                y = L.ffn_apply(p["ffn"], h)
-            return x + y, kp, vp
+            y, _, read = self._ffn(p, h, valid[:, None], experts,
+                                   l - self.n_lead)
+            return x + y, kp, vp, read
 
         # the pool rides in the scan's carry and each layer updates it in
         # place; as xs/ys it would be sliced per layer into a fresh
         # pool-sized buffer and copied back over the donated one every step
-        ks, vs = k_pages, v_pages
+        ks, vs, read = k_pages, v_pages, jnp.int32(0)
         for i, p in enumerate(params.get("lead_blocks", [])):
-            x, ks, vs = step_block(p, x, ks, vs, i)
+            x, ks, vs, r = step_block(p, x, ks, vs, i)
+            read = read + r
+
+        blocks, experts = self._split_experts(params["blocks"])
 
         def body(carry, xs):
+            x, ks, vs, read = carry
             p, l = xs
-            return step_block(p, *carry, l), None
+            x, ks, vs, r = step_block(p, x, ks, vs, l, experts)
+            return (x, ks, vs, read + r), None
 
-        (x, ks, vs), _ = lax.scan(
-            body, (x, ks, vs),
-            (params["blocks"],
-             jnp.arange(self.n_lead, cfg.num_layers, dtype=jnp.int32)))
+        (x, ks, vs, read), _ = lax.scan(
+            body, (x, ks, vs, read),
+            (blocks, self.n_lead + self._scan_index()))
 
         x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
         head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-        return (x[:, -1] @ head), ks, vs
+        return _counted((x[:, -1] @ head, ks, vs), read, count_experts)
+
+
+def _counted(out: tuple, read: jax.Array, count_experts: bool) -> tuple:
+    """A step's outputs, with the experts it read appended when asked."""
+    return out + (read,) if count_experts else out

@@ -397,7 +397,8 @@ def test_batch_state_is_macro_done_locked_view():
 def test_moe_router_combine_survives_strict_promotion():
     """devmem/CI-dtype-leg find: the router combine multiplied f32
     weights by a raw bool keep-mask — f32*bool has no promotion path
-    under jax_numpy_dtype_promotion=strict.  Pin the .astype fix."""
+    under jax_numpy_dtype_promotion=strict.  Pin that the combine, with
+    a padding mask, stays clean under strict promotion."""
     import jax
     import jax.numpy as jnp
     from repro.configs.base import MoEConfig
@@ -406,10 +407,12 @@ def test_moe_router_combine_survives_strict_promotion():
     cfg = MoEConfig(num_experts=4, top_k=2, d_ff_expert=8)
     p = moe_init(jax.random.PRNGKey(0), 16, cfg, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 4, 16), jnp.float32)
+    valid = jnp.arange(4)[None, :] < jnp.asarray([[4], [2]])
     with jax.numpy_dtype_promotion("strict"):
-        out, aux = moe_ffn(x, p, cfg)
-    assert out.shape == x.shape
-    assert out.dtype == jnp.float32
+        out, aux, _ = moe_ffn(x, p, cfg)
+        masked, _, _ = moe_ffn(x, p, cfg, valid)
+    assert out.shape == masked.shape == x.shape
+    assert out.dtype == masked.dtype == jnp.float32
 
 
 def test_kvcache_hbm_bytes_uses_pool_dtype():

@@ -6,7 +6,7 @@ from repro.configs.base import shape_applicable
 
 EXPECTED = {
     # arch: (L, d_model, H, kv, d_ff, vocab)
-    "deepseek-moe-16b": (28, 2048, 16, 16, 1408, 102400),
+    "deepseek-moe-16b": (28, 2048, 16, 16, 10944, 102400),
     "mixtral-8x22b": (56, 6144, 48, 8, 16384, 32768),
     "whisper-tiny": (4, 384, 6, 6, 1536, 51865),
     "deepseek-67b": (95, 8192, 64, 8, 22016, 102400),

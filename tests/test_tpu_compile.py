@@ -162,3 +162,35 @@ def test_full_width_prefill_step(one_chip, impl):
         params, S((1, 48), jnp.int32), cache, S((1,), jnp.int32))
     assert _device_bytes(compiled) < HBM_LIMIT
 
+
+
+def test_moe_decode_step_reads_its_experts_out_of_the_stack(one_chip,
+                                                           monkeypatch):
+    """The engine's paged decode step of deepseek-moe-16b at its
+    published widths, 7 layers as one pipeline stage (the benchmark's
+    deepseek-moe-16b-pp4), with the Pallas grouped matmul compiled for
+    the chip: the layer scan hands each layer's grouped matmuls the
+    stacked experts of all 6 MoE layers, so no op makes a layer's (64,
+    d, F) copy, the temporaries stay under one layer's expert matrix,
+    and the step fits the chip."""
+    from repro.engine.models import moe as M
+    monkeypatch.setattr(M, "env_interpret", lambda interpret: False)
+    mc = get_config("deepseek-moe-16b").replace(num_layers=7)
+    model = build_model(mc)
+    params = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    S = lambda *a: _spec(one_chip, *a)
+    layers, kv_heads, head_dim = model.paged_kv_layout()
+    pool = S((layers, POOL_PAGES, PAGE, kv_heads, head_dim), jnp.float32)
+    compiled = _compile(
+        lambda p, tok, kp, vp, pt, ln: model.paged_decode_step(
+            p, tok, kp, vp, pt, ln, count_experts=True),
+        params, S((B,), jnp.int32), pool, pool, S((B, N_PAGES), jnp.int32),
+        S((B,), jnp.int32), donate_argnums=(2, 3))
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') >= 3
+    assert not re.search(r"= bf16\[64,(2048,1408|1408,2048)\]", hlo)
+    m = mc.moe
+    expert_matrix = m.num_experts * mc.d_model * m.d_ff_expert * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < expert_matrix
+    assert _device_bytes(compiled) < HBM_LIMIT
