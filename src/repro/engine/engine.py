@@ -42,6 +42,7 @@ lowers under pjit for the dry-run meshes.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 import time
@@ -115,6 +116,8 @@ class EngineStats:
     decode_kv_tokens: int = 0            # live KV tokens read, summed over steps
     pool_pages_used: int = 0             # pages not free, summed over steps
     pool_pages_total: int = 0            # num_pages, summed over steps
+    pool_writes: int = 0                 # donated pool-writer dispatches
+    pool_write_rows: int = 0             # token rows they wrote (COW too)
     admit_deferrals: int = 0             # admissions deferred for pages
     moe_token_slots: int = 0             # token-expert slots computed
     moe_experts_read: int = 0            # experts with a slot, summed over
@@ -372,16 +375,16 @@ class InferenceEngine:
 
     def hbm_bytes(self) -> int:
         """Device bytes this engine holds at its peak once loaded: the
-        params, the KV pool, and a second pool.  The paged decode step
-        updates the donated pool in place, but ``PagedKVCache`` writes
-        prefilled and copy-on-write pages with eager ``.at[].set``, which
-        builds a new pool while the old one is still alive."""
+        params and one KV pool.  Every write into the pool — the paged
+        decode step's, and ``PagedKVCache``'s writer for prefilled,
+        copy-on-write and imported pages — takes the pool donated and
+        updates it in place, so no second pool is ever alive."""
         if not self._paged_layout:
             return self.param_bytes()
         layers, kv_heads, head_dim = self._paged_layout
         pool = PagedKVCache.pool_bytes(layers, self.num_pages,
                                        self.page_size, kv_heads, head_dim)
-        return self.param_bytes() + 2 * pool
+        return self.param_bytes() + pool
 
     # ----------------------------------------------------------- submission
     def submit(self, prompt: Sequence[int], *, max_new_tokens: int = 16,
@@ -588,7 +591,8 @@ class InferenceEngine:
                 headroom = len(kv.free_pages) - self._reserved_pages()
             if headroom < need:
                 return 0
-            seq = kv.import_sequence(k, v)
+            with self._counting_pool_writes():
+                seq = kv.import_sequence(k, v)
             self.stats.h2d_bytes += k.nbytes + v.nbytes   # staging upload
             self._warm[seq] = tokens
             self._warm.move_to_end(seq)
@@ -815,6 +819,22 @@ class InferenceEngine:
                                    kv_heads, head_dim)
         return self.kv
 
+    @contextlib.contextmanager
+    # requires: self._cv | engine-loop
+    def _counting_pool_writes(self, sp=None):
+        """Count the pool-writer dispatches and rows made inside the
+        block onto the stats and, as ``pool_writes``, onto ``sp``."""
+        kv = self.kv
+        if kv is None:
+            yield
+            return
+        writes, rows = kv.pool_writes, kv.pool_write_rows
+        yield
+        self.stats.pool_writes += kv.pool_writes - writes
+        self.stats.pool_write_rows += kv.pool_write_rows - rows
+        if sp is not None:
+            sp.set(pool_writes=kv.pool_writes - writes)
+
     def _remove_pending(self, req: _Request) -> None:
         with self._cv:
             try:
@@ -903,7 +923,9 @@ class InferenceEngine:
         with obs.span("engine.prefill", rid=req.rid, prompt_tokens=S,
                       shared_tokens=shared) as sp:
             self._queue_done(req, sp.t0)
-            logits, read = self._prefill_into(slot, donor, shared, shareable)
+            with self._counting_pool_writes(sp):
+                logits, read = self._prefill_into(slot, donor, shared,
+                                                  shareable)
             if self._moe_slots:
                 slots = (S - shared) * self._moe_slots
                 self.stats.moe_token_slots += slots
@@ -1101,7 +1123,8 @@ class InferenceEngine:
         """One decode step over every active slot: host preparation and
         dispatch (span ``engine.decode``), then sampling and the sync."""
         b_real = len(self._active)
-        with obs.span("engine.decode", rows=b_real) as sp:
+        with obs.span("engine.decode", rows=b_real) as sp, \
+                self._counting_pool_writes():
             logits, read = (self._decode_paged(sp) if self._use_paged
                             else self._decode_dense())
             if self._moe_slots:

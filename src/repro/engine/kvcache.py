@@ -11,6 +11,16 @@ device gather it falls back to) reads the pages in place — the KV bytes
 never round-trip through the host.  Only METADATA lives on the host:
 refcounts, the free list, per-sequence page tables and lengths.
 
+Every write into the pool outside the decode step (prefilled pages,
+a partial-page fill, a copy-on-write page copy, a migrated import, the
+dense-view reference path's appends) is ONE jitted program that takes
+the k and v pools, donated on device backends, and returns them: XLA
+scatters in place, so no pool-sized copy or second pool ever exists.
+Page ids and offsets are traced int32 operands and the rows written are
+padded to a power-of-two number of pages (padding targets page
+``num_pages`` and is dropped), so a pool layout compiles at most
+``ceil(log2(num_pages)) + 1`` writer shapes per row dtype.
+
 This is the authoritative KV store behind the continuous-batching
 ``InferenceEngine``: every full-attention transformer sequence lives
 here from admission to retirement.  Host staging happens exactly at the
@@ -31,8 +41,48 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+def pool_copy(k, v, cow):
+    """Copy whole pages ``cow[0] -> cow[1]`` (int32 ``(2, C)``) in both
+    pools; a destination of ``num_pages`` is padding and is dropped."""
+    src, dst = cow[0], cow[1]
+    return (k.at[:, dst].set(k[:, src], mode="drop"),
+            v.at[:, dst].set(v[:, src], mode="drop"))
+
+
+def pool_write(k, v, cow, k_rows, v_rows, at):
+    """The page copies of :func:`pool_copy`, then token row ``i`` of
+    ``k_rows``/``v_rows`` ``(L, R, Hkv, Dh)``, cast to the pool's dtype,
+    into slot ``at[1, i]`` of page ``at[0, i]`` (int32 ``(2, R)``; page
+    ``num_pages`` is padding and is dropped)."""
+    pages, offs = at[0], at[1]
+
+    def layer(i, pools):
+        # a layer at a time, so only one layer's rows are ever cast
+        return tuple(p.at[i, pages, offs].set(r[i].astype(p.dtype),
+                                              mode="drop")
+                     for p, r in zip(pools, (k_rows, v_rows)))
+
+    return jax.lax.fori_loop(0, k.shape[0], layer, pool_copy(k, v, cow))
+
+
+def _pow2_at_least(n: int, start: int = 1) -> int:
+    """The least ``start * 2**i`` that is at least ``n``."""
+    b = start
+    while b < n:
+        b *= 2
+    return b
+
+
+def _pad_rows(a, n: int):
+    """Zero-pad ``a`` (L, S, Hkv, Dh) to ``n`` token rows: on the host
+    for a host block (the migration staging path), else on device."""
+    pad = ((0, 0), (0, n - a.shape[1]), (0, 0), (0, 0))
+    return np.pad(a, pad) if isinstance(a, np.ndarray) else jnp.pad(a, pad)
 
 
 @dataclass
@@ -66,9 +116,20 @@ class PagedKVCache:  # requires: InferenceEngine._cv | engine-loop
         self.free_pages: List[int] = list(range(num_pages - 1, -1, -1))
         self.sequences: Dict[int, SequenceEntry] = {}
         self._next_seq = 0
+        # copy-on-write page copies (src, dst) queued by _cow_last_page;
+        # the public method that queued them dispatches them before it
+        # returns, so none outlives the call
+        self._copies: List[Tuple[int, int]] = []
+        # the pools flow through the writers; donating them lets XLA
+        # write in place on device backends (CPU ignores it)
+        donate = (0, 1) if jax.default_backend() != "cpu" else ()
+        self._copy_jit = jax.jit(pool_copy, donate_argnums=donate)
+        self._write_jit = jax.jit(pool_write, donate_argnums=donate)
         # stats
         self.pages_shared = 0
         self.tokens_reused = 0
+        self.pool_writes = 0             # donated writer dispatches
+        self.pool_write_rows = 0         # token rows written, COW rows too
 
     # ------------------------------------------------------------ alloc/free
     def _alloc_page(self) -> int:
@@ -91,32 +152,43 @@ class PagedKVCache:  # requires: InferenceEngine._cv | engine-loop
         return int((self.refcount > 0).sum())
 
     # ----------------------------------------------------- device plumbing
-    def _page_blocks(self, a) -> jnp.ndarray:
-        """(L, S, Hkv, Dh) -> (L, n_pages, page, Hkv, Dh), zero-padded to
-        whole pages, in pool dtype on device."""
-        a = jnp.asarray(a, self.dtype)
-        S = a.shape[1]
-        ps = self.page_size
-        n = -(-S // ps)
-        pad = n * ps - S
-        if pad:
-            a = jnp.pad(a, ((0, 0), (0, pad), (0, 0), (0, 0)))
-        return a.reshape(self.num_layers, n, ps, self.kv_heads, self.head_dim)
-
-    def _write_pages(self, pages: List[int], k, v) -> None:
-        """Scatter whole-page blocks into freshly allocated pages."""
-        idx = jnp.asarray(pages, jnp.int32)
-        self.k = self.k.at[:, idx].set(self._page_blocks(k))
-        self.v = self.v.at[:, idx].set(self._page_blocks(v))
+    def _write(self, k=None, v=None,
+               targets: List[Tuple[int, int]] = ()) -> None:
+        """Dispatch the queued copy-on-write page copies and the token
+        rows of ``k``/``v`` (L, S, Hkv, Dh), row ``i`` at ``targets[i] =
+        (page, offset)``, as one donated program.  ``targets`` may run
+        past ``S`` to the end of the last page: those slots get zero
+        rows, as a whole-page write always left them."""
+        copies, self._copies = self._copies, []
+        if not copies and not targets:
+            return
+        n_pages = self.num_pages
+        cow = np.zeros((2, _pow2_at_least(len(copies))), np.int32)
+        cow[1] = n_pages
+        if copies:
+            cow[:, :len(copies)] = list(zip(*copies))
+        if targets:
+            # a power of two pages, at most the pool's tokens
+            r = min(_pow2_at_least(len(targets), self.page_size),
+                    n_pages * self.page_size)
+            at = np.zeros((2, r), np.int32)
+            at[0] = n_pages
+            at[:, :len(targets)] = list(zip(*targets))
+            self.k, self.v = self._write_jit(
+                self.k, self.v, cow, _pad_rows(k, r), _pad_rows(v, r), at)
+            self.pool_write_rows += k.shape[1]
+        else:
+            self.k, self.v = self._copy_jit(self.k, self.v, cow)
+        self.pool_writes += 1
+        self.pool_write_rows += len(copies) * self.page_size
 
     def _cow_last_page(self, e: SequenceEntry) -> int:
-        """Make the trailing page of ``e`` private (device page copy when
-        it is aliased); returns the (possibly new) page id."""
+        """Make the trailing page of ``e`` private (queue a device page
+        copy when it is aliased); returns the (possibly new) page id."""
         p = e.page_ids[-1]
         if self.refcount[p] > 1:                 # copy-on-write partial page
             newp = self._alloc_page()
-            self.k = self.k.at[:, newp].set(self.k[:, p])
-            self.v = self.v.at[:, newp].set(self.v[:, p])
+            self._copies.append((p, newp))
             self._unref_page(p)
             e.page_ids[-1] = newp
             p = newp
@@ -164,70 +236,68 @@ class PagedKVCache:  # requires: InferenceEngine._cv | engine-loop
             self.pages_shared += n_alias
             self.tokens_reused += shared_len
 
-        S = 0 if k is None else k.shape[1]
-        if S:
+        self.sequences[seq_id] = SequenceEntry(seq_id, page_ids, length)
+        if k is not None and k.shape[1]:
             assert length % ps == 0, \
                 "bulk suffix requires a page-aligned shared prefix; " \
                 "extend_sequence() handles the copy-on-write case"
-            pages = [self._alloc_page() for _ in range(-(-S // ps))]
-            self._write_pages(pages, k, v)
-            page_ids.extend(pages)
-            length += S
-        self.sequences[seq_id] = SequenceEntry(seq_id, page_ids, length)
+            try:
+                self.extend_sequence(seq_id, k, v)
+            except MemoryError:
+                self.free_sequence(seq_id)
+                raise
         return seq_id
 
     def extend_sequence(self, seq_id: int, k, v) -> None:
         """Append a bulk KV block (L, S, Hkv, Dh) at the sequence tail.
 
         Fills the trailing partial page first (copy-on-write if it is
-        aliased), then scatters whole pages — O(1) device calls however
-        long the block, which is how chunked prefill writes its suffix
-        through the pool without a per-token loop."""
+        aliased), then whole fresh pages, zero-padded past the block —
+        ONE donated device dispatch however long the block, which is how
+        chunked prefill writes its suffix through the pool without a
+        per-token loop."""
         e = self.sequences[seq_id]
-        k = jnp.asarray(k, self.dtype)
-        v = jnp.asarray(v, self.dtype)
         S = k.shape[1]
+        if not S:
+            return
         ps = self.page_size
         off = e.length % ps
-        if off and S:
+        targets: List[Tuple[int, int]] = []
+        if off:
             p = self._cow_last_page(e)
-            n = min(ps - off, S)
-            self.k = self.k.at[:, p, off:off + n].set(k[:, :n])
-            self.v = self.v.at[:, p, off:off + n].set(v[:, :n])
-            e.length += n
-            k, v = k[:, n:], v[:, n:]
-            S -= n
-        if S:
-            pages = [self._alloc_page() for _ in range(-(-S // ps))]
-            self._write_pages(pages, k, v)
-            e.page_ids.extend(pages)
-            e.length += S
+            targets = [(p, o) for o in range(off, min(ps, off + S))]
+        pages = [self._alloc_page()
+                 for _ in range(-(-(S - len(targets)) // ps))]
+        targets += [(p, o) for p in pages for o in range(ps)]
+        self._write(k, v, targets)
+        e.page_ids.extend(pages)
+        e.length += S
 
     def append_token(self, seq_id: int, k_t, v_t) -> None:
         """k_t/v_t: (L, Hkv, Dh) — one decode step's KV (device scatter)."""
-        e = self.sequences[seq_id]
-        p, slot = self.prepare_append(seq_id)
-        self.k = self.k.at[:, p, slot].set(jnp.asarray(k_t, self.dtype))
-        self.v = self.v.at[:, p, slot].set(jnp.asarray(v_t, self.dtype))
-        e.length += 1
+        self.append_tokens([seq_id], k_t[:, None], v_t[:, None])
 
     def append_tokens(self, seq_ids: List[int], k_t, v_t) -> None:
         """One decode step's KV for a whole batch: k_t/v_t (L, B, Hkv, Dh).
 
         Allocates / copy-on-writes each sequence's trailing page, then
-        lands every row in ONE device scatter (the dense-view reference
-        path's append; the paged path scatters inside the decode jit)."""
-        pages, slots = [], []
-        for sid in seq_ids:
-            p, s = self.prepare_append(sid)
-            pages.append(p)
-            slots.append(s)
-        pi = jnp.asarray(pages, jnp.int32)
-        si = jnp.asarray(slots, jnp.int32)
-        self.k = self.k.at[:, pi, si].set(jnp.asarray(k_t, self.dtype))
-        self.v = self.v.at[:, pi, si].set(jnp.asarray(v_t, self.dtype))
+        lands the page copies and every row in ONE device dispatch (the
+        dense-view reference path's append; the paged path scatters
+        inside the decode jit)."""
+        targets = [self._prepare_append(sid) for sid in seq_ids]
+        self._write(k_t, v_t, targets)
         for sid in seq_ids:
             self.sequences[sid].length += 1
+
+    def _prepare_append(self, seq_id: int) -> Tuple[int, int]:
+        """:meth:`prepare_append` with any copy-on-write copy left
+        queued for the caller's dispatch."""
+        e = self.sequences[seq_id]
+        slot = e.length % self.page_size
+        if slot == 0:
+            e.page_ids.append(self._alloc_page())
+            return e.page_ids[-1], 0
+        return self._cow_last_page(e), slot
 
     def prepare_append(self, seq_id: int) -> Tuple[int, int]:
         """Host-metadata half of a one-token append: allocate the next
@@ -236,12 +306,9 @@ class PagedKVCache:  # requires: InferenceEngine._cv | engine-loop
         caller writes the KV (device scatter — possibly inside a jitted
         decode step) and then bumps the length via
         :meth:`commit_append`."""
-        e = self.sequences[seq_id]
-        slot = e.length % self.page_size
-        if slot == 0:
-            e.page_ids.append(self._alloc_page())
-            return e.page_ids[-1], 0
-        return self._cow_last_page(e), slot
+        target = self._prepare_append(seq_id)
+        self._write()
+        return target
 
     def commit_append(self, seq_id: int, n: int = 1) -> None:
         self.sequences[seq_id].length += n
@@ -254,12 +321,14 @@ class PagedKVCache:  # requires: InferenceEngine._cv | engine-loop
         fresh page, aliased trailing pages were copy-on-written), which
         is the safety contract the fused append+attend kernel relies on
         to write ``(page, offset)`` slots inside the attention dispatch.
+        The copy-on-write copies of the whole batch are one dispatch.
         Returns ``(pages, offsets)`` parallel to ``seq_ids``."""
         pages, offsets = [], []
         for sid in seq_ids:
-            p, o = self.prepare_append(sid)
+            p, o = self._prepare_append(sid)
             pages.append(p)
             offsets.append(o)
+        self._write()
         return pages, offsets
 
     def commit_appends(self, seq_ids: List[int], n: int = 1) -> None:

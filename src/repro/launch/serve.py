@@ -65,8 +65,7 @@ def workers_that_fit(models, requested: int) -> Tuple[int, str]:
     need = max(InferenceEngine(cfg).hbm_bytes() for cfg in models.values())
     n = max(1, min(requested, int(limit // need)))
     return n, (f"{n} worker(s): each needs {need / 1e9:.2f} GB (params + KV "
-               f"pool + the pool copy of an eager page write), device limit "
-               f"{limit / 1e9:.2f} GB")
+               f"pool), device limit {limit / 1e9:.2f} GB")
 
 
 def solve_plan(graph, cons, hardware: HardwareProfile, workers: int):

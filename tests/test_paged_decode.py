@@ -5,6 +5,7 @@ acceptance, grace-window admission and claim throttling.
 
 Fast suite: tiny configs, n<=3 queries, decode_cap<=3 for e2e runs.
 """
+import math
 import threading
 import time
 
@@ -262,6 +263,121 @@ def test_page_scatter_append_round_trip_matches_append_token():
         np.testing.assert_array_equal(np.asarray(kr), np.asarray(kd))
         np.testing.assert_array_equal(np.asarray(vr), np.asarray(vd))
     assert ref.pages_in_use == dev.pages_in_use
+
+
+def _eager_blocks(a, ps):
+    """(L, S, H, D) -> whole zero-padded pages, as the eager page write
+    built them."""
+    n = -(-a.shape[1] // ps)
+    a = np.pad(a, ((0, 0), (0, n * ps - a.shape[1]), (0, 0), (0, 0)))
+    return a.reshape(a.shape[0], n, ps, *a.shape[2:])
+
+
+@pytest.mark.parametrize("case", ["cold", "shared_aligned",
+                                  "shared_unaligned", "shared_in_page",
+                                  "cow_first_decode", "import"])
+def test_pool_writer_matches_eager_page_writes(case):
+    """Each write into the pool outside the decode step is ONE writer
+    dispatch whose pools come out bitwise equal to the eager
+    ``.at[].set`` writes it replaced: whole zero-padded pages, the
+    partial-page fill, the copy-on-write page copy.  Pages of other
+    sequences and rows past the sequence's end are untouched."""
+    ps, L, H, D = 4, 2, 2, 8
+    rng = np.random.default_rng(7)
+    rows = lambda n: rng.standard_normal((L, n, H, D)).astype(np.float32)
+    pc = PagedKVCache(num_layers=L, num_pages=16, page_size=ps,
+                      kv_heads=H, head_dim=D)
+    # stale contents everywhere, so an untouched row differs from zero
+    pc.k = jnp.asarray(rng.standard_normal(pc.k.shape), jnp.float32)
+    pc.v = jnp.asarray(rng.standard_normal(pc.v.shape), jnp.float32)
+    donor = pc.add_sequence(jnp.asarray(rows(10), jnp.bfloat16),
+                            jnp.asarray(rows(10), jnp.bfloat16))
+    other = pc.add_sequence(rows(5), rows(5))
+    k0, v0 = np.array(pc.k), np.array(pc.v)
+    writes, written = pc.pool_writes, pc.pool_write_rows
+    k_new = jnp.asarray(rows(7), jnp.bfloat16)   # a prefill's rows
+    v_new = jnp.asarray(rows(7), jnp.bfloat16)
+    f32 = lambda a: np.asarray(a, np.float32)
+    want_k, want_v = k0.copy(), v0.copy()
+
+    def whole_pages(pages, k, v):
+        want_k[:, pages] = _eager_blocks(f32(k), ps)
+        want_v[:, pages] = _eager_blocks(f32(v), ps)
+
+    def cow(src, dst):
+        want_k[:, dst] = want_k[:, src]
+        want_v[:, dst] = want_v[:, src]
+
+    if case == "cold":
+        seq = pc.add_sequence(k_new, v_new)
+        whole_pages(pc.page_table(seq), k_new, v_new)
+        rows_written = 7
+    elif case == "shared_aligned":
+        seq = pc.add_sequence(k_new, v_new, shared_from=donor, shared_len=8)
+        assert pc.page_table(seq)[:2] == pc.page_table(donor)[:2]
+        whole_pages(pc.page_table(seq)[2:], k_new, v_new)
+        rows_written = 7
+    elif case == "shared_unaligned":            # 6 shared: 2 into page 1
+        seq = pc.add_sequence(shared_from=donor, shared_len=6)
+        pc.extend_sequence(seq, k_new, v_new)
+        table = pc.page_table(seq)
+        cow(pc.page_table(donor)[1], table[1])
+        want_k[:, table[1], 2:] = f32(k_new[:, :2])
+        want_v[:, table[1], 2:] = f32(v_new[:, :2])
+        whole_pages(table[2:], k_new[:, 2:], v_new[:, 2:])
+        rows_written = 7 + ps
+    elif case == "shared_in_page":              # ends inside page 1
+        seq = pc.add_sequence(shared_from=donor, shared_len=5)
+        pc.extend_sequence(seq, k_new[:, :2], v_new[:, :2])
+        table = pc.page_table(seq)
+        cow(pc.page_table(donor)[1], table[1])
+        want_k[:, table[1], 1:3] = f32(k_new[:, :2])
+        want_v[:, table[1], 1:3] = f32(v_new[:, :2])
+        rows_written = 2 + ps
+    elif case == "cow_first_decode":            # first append COWs page 1
+        seq = pc.add_sequence(shared_from=donor, shared_len=5)
+        pc.prepare_appends([seq])
+        table = pc.page_table(seq)
+        assert table[1] != pc.page_table(donor)[1]
+        cow(pc.page_table(donor)[1], table[1])
+        rows_written = ps
+    else:                                       # a migrated host block
+        k_host, v_host = rows(7), rows(7)
+        seq = pc.import_sequence(k_host, v_host)
+        whole_pages(pc.page_table(seq), k_host, v_host)
+        rows_written = 7
+    assert pc.pool_writes == writes + 1
+    assert pc.pool_write_rows == written + rows_written
+    np.testing.assert_array_equal(np.asarray(pc.k), want_k)
+    np.testing.assert_array_equal(np.asarray(pc.v), want_v)
+    private = [p for p in pc.page_table(seq) if pc.refcount[p] == 1]
+    for s in (donor, other):
+        kept = [p for p in pc.page_table(s) if p not in private]
+        np.testing.assert_array_equal(np.asarray(pc.k)[:, kept],
+                                      k0[:, kept])
+    # past the sequence's end, its last page holds what the eager
+    # writes left there (zeros after a whole-page write, the donor's
+    # rows after a copy-on-write), nothing the writer made up
+    n = pc.sequences[seq].length
+    last = pc.page_table(seq)[-1]
+    np.testing.assert_array_equal(np.asarray(pc.k)[:, last, n % ps or ps:],
+                                  want_k[:, last, n % ps or ps:])
+
+
+def test_pool_writer_compiles_a_bounded_set_of_shapes():
+    """Prompts of 1 to 2100 tokens build at most ceil(log2(pages)) + 1
+    writer programs: the rows written are bucketed to a power of two
+    pages, and page ids are traced operands."""
+    pc = PagedKVCache(num_layers=1, num_pages=300, page_size=8,
+                      kv_heads=1, head_dim=2)
+    built = pc._write_jit._cache_size()     # one cache per pool_write
+    for n in range(1, 2101):
+        seq = pc.add_sequence(np.ones((1, n, 1, 2), np.float32),
+                              np.ones((1, n, 1, 2), np.float32))
+        pc.free_sequence(seq)
+    built = pc._write_jit._cache_size() - built
+    assert built <= math.ceil(math.log2(300)) + 1
+    assert pc.pool_writes == 2100
 
 
 # ---------------------------------------------------------------------------

@@ -194,3 +194,34 @@ def test_moe_decode_step_reads_its_experts_out_of_the_stack(one_chip,
     expert_matrix = m.num_experts * mc.d_model * m.d_ff_expert * 2
     assert compiled.memory_analysis().temp_size_in_bytes < expert_matrix
     assert _device_bytes(compiled) < HBM_LIMIT
+
+
+@pytest.mark.parametrize("rows", [PAGE, POOL_PAGES * PAGE],
+                         ids=["one-page", "whole-pool"])
+@pytest.mark.parametrize("name, layers", [("qwen3-1.7b", None),
+                                          ("deepseek-moe-16b", 7)],
+                         ids=["qwen3-1.7b", "deepseek-moe-16b-pp4"])
+def test_pool_write_updates_pool_in_place(one_chip, name, layers, rows):
+    """The pool writer (prefilled, copy-on-write and imported pages) at
+    its one-page and its largest row bucket, pools donated as on the
+    chip: no pool-sized temporary (under one layer's K+V slice), both
+    pools aliased to the outputs, and no copy of the whole pool."""
+    from repro.engine.kvcache import pool_write
+    cfg = get_config(name)
+    if layers:
+        cfg = cfg.replace(num_layers=layers)
+    n_layers, kv_heads, head_dim = build_model(cfg).paged_kv_layout()
+    S = lambda *a: _spec(one_chip, *a)
+    shape = (n_layers, POOL_PAGES, PAGE, kv_heads, head_dim)
+    pool = S(shape, jnp.float32)
+    new = S((n_layers, rows, kv_heads, head_dim), jnp.bfloat16)
+    compiled = _compile(pool_write, pool, pool, S((2, 1), jnp.int32),
+                        new, new, S((2, rows), jnp.int32),
+                        donate_argnums=(0, 1))
+    m = compiled.memory_analysis()
+    assert m.temp_size_in_bytes < 2 * math.prod(shape[1:]) * 4
+    assert m.alias_size_in_bytes >= 2 * math.prod(shape) * 4
+    hlo_shape = re.escape("f32[%s]" % ",".join(map(str, shape)))
+    copies = re.findall(rf"= {hlo_shape}(?:{{[^}}]*}})? copy(?:-start)?\(",
+                        compiled.as_text())
+    assert not copies
